@@ -40,6 +40,8 @@ def main(argv=None):
                          "JSON (commit as benchmarks/BASELINE.json)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import figures, roofline
     benches = [(f.__name__, f) for f in figures.ALL_FIGURES]
     benches.append(("trace_overhead", trace_overhead))

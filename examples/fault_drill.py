@@ -359,6 +359,8 @@ if __name__ == "__main__":
                     help="also run the causal-profiler drill (critical-path "
                          "RTT attribution, hot-key top-k, Perfetto sub-spans)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if not args.skip_train:
         train_drill()
     store_drill()

@@ -14,10 +14,12 @@ import numpy as np
 
 from repro.core import DMConfig, FuseeCluster, Op
 from repro.core.api import KVStore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import DeviceBackend, PoolConfig
 
 
 def main():
+    enable_compile_cache()
     print("== 1. event-level FUSEE store (paper protocol, verb by verb) ==")
     cluster = FuseeCluster(DMConfig(num_mns=4, replication=3), num_clients=2)
     kv = cluster.store(0)

@@ -11,10 +11,12 @@ import numpy as np
 from repro.configs import base as C
 from repro.models import build
 from repro.serving import PoolConfig, Request, ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
 def main():
+    enable_compile_cache()
     mesh = make_host_mesh((1, 1), ("data", "model"))
     cfg = C.reduced(C.get("llama3-8b"))
     model = build(cfg, mesh, use_kernels=True)   # Pallas attn (interpret)

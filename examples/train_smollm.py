@@ -14,6 +14,7 @@ from repro.data import DataConfig, SyntheticLM
 from repro.models import build
 from repro.optim import OptConfig
 from repro.train import TrainConfig, Trainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--ckpt", default="/tmp/repro_train_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = C.get(args.arch)
     if not args.full:

@@ -407,13 +407,10 @@ class SimBackend:
 
     def _race_lookup(self, q: np.ndarray, shadow: np.ndarray):
         if self.use_kernel:
-            try:
-                # batched kernel entry point: Pallas on TPU, the bit-exact
-                # numpy mirror elsewhere (kernels/race_lookup/ops.py)
-                from repro.kernels import race_lookup_batch
-                return race_lookup_batch(q, shadow)
-            except Exception:       # pragma: no cover - jax-less fallback
-                pass
+            # batched kernel entry point: Pallas on TPU, the bit-exact
+            # numpy mirror elsewhere (kernels/race_lookup/ops.py)
+            from repro.kernels import race_lookup_batch
+            return race_lookup_batch(q, shadow)
         return race_lookup_np(q, shadow)
 
     # -------------------------------------------------------------- driving

@@ -471,11 +471,8 @@ class FleetEngine:
 
     def _race_lookup(self, q: np.ndarray, shadow: np.ndarray):
         if self.use_kernel:
-            try:
-                from repro.kernels import race_lookup_batch
-                return race_lookup_batch(q, shadow)
-            except Exception:       # pragma: no cover - jax-less fallback
-                pass
+            from repro.kernels import race_lookup_batch
+            return race_lookup_batch(q, shadow)
         return race_lookup_np(q, shadow)
 
     def locate_wave(self, wave: Sequence[Tuple[SimBackend, Sequence[Op]]]

@@ -190,11 +190,8 @@ def locate_leaves(client, starts: List[int]) -> List[int]:
 
 
 def _leaf_probe(starts: np.ndarray, lows: np.ndarray):
-    try:                                   # Pallas on TPU, numpy elsewhere
-        from repro.kernels import leaf_probe_batch
-        return leaf_probe_batch(starts, lows)
-    except Exception:                      # pragma: no cover - jax-less env
-        return leaf_probe_np(starts, lows)
+    from repro.kernels import leaf_probe_batch   # Pallas on TPU, numpy elsewhere
+    return leaf_probe_batch(starts, lows)
 
 
 # ------------------------------------------------------ region bootstrap --

@@ -9,8 +9,11 @@ pure-jnp oracle (ref.py) and a jit'd wrapper (ops.py):
                     prefetch; the numpy exec_fused_tick stays the CPU
                     authority)
 
-On CPU the kernels execute via ``interpret=True``; on TPU they compile to
-Mosaic.  Correctness is swept over shapes/dtypes in tests/test_kernels.py.
+``platform.py`` decides how they run: compiled to Mosaic on the TPU,
+``interpret=True`` on the CPU, an error anywhere else.  Correctness is
+swept over shapes/dtypes in tests/test_kernels.py; tests/test_tpu_compile.py
+compiles race_lookup and leaf_probe for a described v5e.  fleet_tick does
+not compile for the TPU yet.
 """
 from .flash_attention import flash_attention, flash_attention_ref  # noqa
 from .paged_attention import paged_attention, paged_attention_ref  # noqa

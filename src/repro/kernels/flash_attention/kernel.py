@@ -34,10 +34,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_kv,
 
     def body(kv_i, carry):
         m_prev, l_prev, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kv_i * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(kv_i * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(kv_i * block_kv, block_kv), :].astype(jnp.float32)
+        v = v_ref[pl.ds(kv_i * block_kv, block_kv), :].astype(jnp.float32)
         s = q @ k.T                              # (BQ, BKV) on the MXU
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(

@@ -9,14 +9,10 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from .kernel import flash_attention_fwd
 from .ref import flash_attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
@@ -27,4 +23,4 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
     if not use_kernel:
         return flash_attention_ref(q, k, v, causal=causal)
     return flash_attention_fwd(q, k, v, causal=causal, block_q=block_q,
-                               block_kv=block_kv, interpret=not _on_tpu())
+                               block_kv=block_kv, interpret=interpret_mode())

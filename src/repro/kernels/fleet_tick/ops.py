@@ -9,21 +9,21 @@ numpy ``DMPool.exec_fused_tick`` stays **authoritative** on CPU (it is
 the simulator's replay-oracle-checked engine); this twin covers the
 READ sweep — the tick's only pure gather — for device offload.  The
 mutating sweeps (WRITE/CAS/FAA) update host slab state and stay on the
-host.
+host.  The kernel does not compile for the TPU yet (its one-row
+``(1, region_words)`` blocks break the sublane tiling), so on a TPU the
+entry point raises unless the caller passes ``prefer_kernel=False``.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ..platform import interpret_mode, on_tpu
 from .kernel import fleet_read_fwd
 from .ref import fleet_read_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("n", "use_kernel"))
@@ -34,7 +34,7 @@ def fleet_read(slab_hi, slab_lo, cells, offs, *, n: int,
     if not use_kernel:
         return fleet_read_ref(slab_hi, slab_lo, cells, offs, n=n)
     return fleet_read_fwd(slab_hi, slab_lo, cells, offs, n=n,
-                          interpret=not _on_tpu())
+                          interpret=interpret_mode())
 
 
 def fleet_read_sweep(slab: np.ndarray, region_words: int,
@@ -50,17 +50,12 @@ def fleet_read_sweep(slab: np.ndarray, region_words: int,
     offs = np.ascontiguousarray(offs, np.int64)
     slab2d = slab.reshape(-1, region_words)
     if prefer_kernel is None:
-        prefer_kernel = _on_tpu()
-    if prefer_kernel and len(cells):
-        try:
-            import jax.numpy as jnp
-            hi = jnp.asarray((slab2d >> np.uint64(32)).astype(np.uint32))
-            lo = jnp.asarray((slab2d & np.uint64(0xFFFFFFFF))
-                             .astype(np.uint32))
-            rhi, rlo = fleet_read(hi, lo, jnp.asarray(cells, jnp.int32),
-                                  jnp.asarray(offs, jnp.int32), n=n)
-            return (np.asarray(rhi, np.uint64) << np.uint64(32)) \
-                | np.asarray(rlo, np.uint64)
-        except Exception:       # pragma: no cover - jax-less fallback
-            pass
-    return slab2d[cells[:, None], offs[:, None] + np.arange(n)]
+        prefer_kernel = on_tpu()
+    if not (prefer_kernel and len(cells)):
+        return slab2d[cells[:, None], offs[:, None] + np.arange(n)]
+    hi = jnp.asarray((slab2d >> np.uint64(32)).astype(np.uint32))
+    lo = jnp.asarray((slab2d & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+    rhi, rlo = fleet_read(hi, lo, jnp.asarray(cells, jnp.int32),
+                          jnp.asarray(offs, jnp.int32), n=n)
+    return (np.asarray(rhi, np.uint64) << np.uint64(32)) \
+        | np.asarray(rlo, np.uint64)

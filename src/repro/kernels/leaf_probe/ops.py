@@ -15,16 +15,14 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ordered import leaf_probe_np  # noqa: F401  (re-export)
 
-from .kernel import leaf_probe_fwd
+from ..platform import interpret_mode, on_tpu
+from .kernel import BLOCK_M, leaf_probe_fwd
 from .ref import leaf_probe_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("use_kernel", "block_keys"))
@@ -34,7 +32,7 @@ def leaf_probe(starts_hi, starts_lo, lows_hi, lows_lo, *,
     if not use_kernel:
         return leaf_probe_ref(starts_hi, starts_lo, lows_hi, lows_lo)
     return leaf_probe_fwd(starts_hi, starts_lo, lows_hi, lows_lo,
-                          block_keys=block_keys, interpret=not _on_tpu())
+                          block_keys=block_keys, interpret=interpret_mode())
 
 
 def leaf_probe_batch(starts: np.ndarray, lows: np.ndarray, *,
@@ -50,19 +48,21 @@ def leaf_probe_batch(starts: np.ndarray, lows: np.ndarray, *,
     starts = np.ascontiguousarray(starts, np.uint64)
     lows = np.ascontiguousarray(lows, np.uint64)
     if prefer_kernel is None:
-        prefer_kernel = _on_tpu()
-    if prefer_kernel and len(lows):
-        try:
-            import jax.numpy as jnp
-            n = len(starts)
-            pad = -(-max(n, 1) // block_keys) * block_keys - n
-            sp = np.concatenate([starts, np.zeros(pad, np.uint64)])
-            shi = jnp.asarray((sp >> 32).astype(np.uint32))
-            slo = jnp.asarray((sp & 0xFFFFFFFF).astype(np.uint32))
-            lhi = jnp.asarray((lows >> 32).astype(np.uint32))
-            llo = jnp.asarray((lows & 0xFFFFFFFF).astype(np.uint32))
-            idx = leaf_probe(shi, slo, lhi, llo, block_keys=block_keys)
-            return np.asarray(idx[:n], np.int32)
-        except Exception:       # pragma: no cover - jax-less fallback
-            pass
-    return leaf_probe_np(starts, lows)
+        prefer_kernel = on_tpu()
+    if not (prefer_kernel and len(lows)):
+        return leaf_probe_np(starts, lows)
+    n, m = len(starts), len(lows)
+    pad = -(-max(n, 1) // block_keys) * block_keys - n
+    sp = np.concatenate([starts, np.zeros(pad, np.uint64)])
+    # the fence table grows a power of two at a time, so a growing keydir
+    # compiles O(log M) shapes; the largest-key padding is clamped away
+    lp = np.full(max(BLOCK_M, 1 << (m - 1).bit_length()),
+                 np.uint64(2 ** 64 - 1))
+    lp[:m] = lows
+    idx = leaf_probe(*_halves(sp), *_halves(lp), block_keys=block_keys)
+    return np.minimum(np.asarray(idx[:n], np.int32), m - 1)
+
+
+def _halves(x: np.ndarray):
+    return (jnp.asarray((x >> np.uint64(32)).astype(np.uint32)),
+            jnp.asarray((x & np.uint64(0xFFFFFFFF)).astype(np.uint32)))

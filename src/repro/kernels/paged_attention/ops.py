@@ -5,12 +5,9 @@ from functools import partial
 
 import jax
 
+from ..platform import interpret_mode
 from .kernel import paged_attention_fwd
 from .ref import paged_attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("use_kernel",))
@@ -19,4 +16,4 @@ def paged_attention(q, kc, vc, valid_len, *, use_kernel: bool = True):
     if not use_kernel:
         return paged_attention_ref(q, kc, vc, valid_len)
     return paged_attention_fwd(q, kc, vc, valid_len,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())

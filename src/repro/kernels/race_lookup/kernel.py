@@ -1,68 +1,41 @@
 """Pallas TPU kernel for the batched RACE hash-index probe — the FUSEE
 client SEARCH phase-1 (§4.2) as a serving hot-spot.
 
-TPU adaptation of the one-sided-RDMA probe: the replicated index shard is
-small metadata (n_buckets x slots_per_bucket x 4B; 4096x8 = 128KB) so the
-whole shard is pinned in VMEM via its BlockSpec; keys stream in tiles.
+TPU adaptation of the one-sided-RDMA probe.  The index at a real size
+(32768 buckets x 8 slots for 100K keys) does not fit fast memory, and the
+TPU has no vector gather across sublanes, so the wrapper fetches each
+key's two candidate bucket rows with one XLA gather in HBM (the RDMA_READ
+of both buckets) and hands the kernel the rows transposed, keys on lanes:
 
-The per-key bucket *gather* is the interesting part: TPU has no efficient
-vector gather across sublanes, so the kernel uses the one-hot-matmul trick —
-``one_hot(bucket_ids) @ index`` runs the gather on the MXU.  int32 slots
-don't matmul, so the wrapper pre-splits the index into hi/lo 16-bit halves
-held as f32 (exact: < 2^24), and the kernel recombines after the gather.
+    keys  (1, N)        int32
+    rows  (2*spb, N)    int32   bucket-1 slots, then bucket-2 slots
 
-Grid: (N / BLOCK_KEYS,).  Hashing is int32 xorshift-multiply on the VPU.
+The kernel hashes the fingerprint on the VPU and picks the first slot
+whose fingerprint matches, bucket-1 slots before bucket-2 slots — integer
+work only, so it is bit-exact with ``race_lookup_ref``.
+
+Grid: (N / BLOCK_KEYS,), BLOCK_KEYS a multiple of 128 lanes.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ref import MASK24
+from .ref import MASK24, bucket_pair, fingerprint
 
 
-def _hash32(x, seed: int):
-    import numpy as np
-    x = x.astype(jnp.uint32) + np.uint32(0x9E3779B9 * (seed + 1) & 0xFFFFFFFF)
-    x = (x ^ (x >> 16)) * np.uint32(0x85EBCA6B)
-    x = (x ^ (x >> 13)) * np.uint32(0xC2B2AE35)
-    return (x ^ (x >> 16)).astype(jnp.uint32)
-
-
-def _lookup_kernel(keys_ref, hi_ref, lo_ref, ptr_ref, found_ref,
-                   *, n_buckets, spb):
-    keys = keys_ref[...]                              # (BK,)
-    b1 = (_hash32(keys, 1) % n_buckets).astype(jnp.int32)
-    b2 = (_hash32(keys, 2) % n_buckets).astype(jnp.int32)
-    b2 = jnp.where(b2 == b1, (b1 + 1) % n_buckets, b2)
-    fp = (_hash32(keys, 7) >> 24).astype(jnp.int32)
-    fp = jnp.where(fp == 0, 1, fp)
-
-    # MXU gather: one_hot(bucket) @ index_halves
-    iota = jax.lax.broadcasted_iota(jnp.int32, (keys.shape[0], n_buckets), 1)
-    oh1 = (iota == b1[:, None]).astype(jnp.float32)
-    oh2 = (iota == b2[:, None]).astype(jnp.float32)
-    hi = hi_ref[...]                                  # (n_buckets, spb) f32
-    lo = lo_ref[...]
-    r1 = jnp.concatenate([oh1 @ hi, oh1 @ lo], axis=1)   # (BK, 2*spb)
-    r2 = jnp.concatenate([oh2 @ hi, oh2 @ lo], axis=1)
-    rows_hi = jnp.concatenate([r1[:, :spb], r2[:, :spb]], axis=1)
-    rows_lo = jnp.concatenate([r1[:, spb:], r2[:, spb:]], axis=1)
-    rows = (rows_hi.astype(jnp.int32) * 65536
-            + rows_lo.astype(jnp.int32))              # (BK, 2*spb)
-
-    slot_fp = (rows >> 24) & 0xFF
-    match = slot_fp == fp[:, None]
-    any_match = jnp.any(match, axis=1)
-    first = jnp.argmax(match, axis=1)
-    picked = jnp.sum(jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, match.shape, 1) == first[:, None],
-        rows, 0), axis=1)
-    ptr_ref[...] = jnp.where(any_match, picked & MASK24, 0).astype(jnp.int32)
-    found_ref[...] = any_match
+def _lookup_kernel(keys_ref, rows_ref, ptr_ref, found_ref):
+    fp = fingerprint(keys_ref[...])                   # (1, BK)
+    rows = rows_ref[...]                              # (2*spb, BK)
+    n_slots = rows.shape[0]
+    match = ((rows >> 24) & 0xFF) == fp
+    slot = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+    first = jnp.min(jnp.where(match, slot, n_slots), axis=0, keepdims=True)
+    found = first < n_slots
+    picked = jnp.sum(jnp.where(slot == first, rows, 0), axis=0, keepdims=True)
+    ptr_ref[...] = jnp.where(found, picked & MASK24, 0)
+    found_ref[...] = found.astype(jnp.int32)
 
 
 def race_lookup_fwd(keys, index, *, block_keys: int = 256,
@@ -72,25 +45,16 @@ def race_lookup_fwd(keys, index, *, block_keys: int = 256,
     nb, spb = index.shape
     block_keys = min(block_keys, N)
     assert N % block_keys == 0
-    # pre-split into f32-exact 16-bit halves (the MXU gather operand)
-    u = index.astype(jnp.uint32)
-    hi = (u >> 16).astype(jnp.float32)
-    lo = (u & 0xFFFF).astype(jnp.float32)
-
-    kernel = functools.partial(_lookup_kernel, n_buckets=nb, spb=spb)
-    return pl.pallas_call(
-        kernel,
+    b1, b2 = bucket_pair(keys, nb)
+    rows = jnp.concatenate([index[b1], index[b2]], axis=1).T   # (2*spb, N)
+    key_spec = pl.BlockSpec((1, block_keys), lambda i: (0, i))
+    ptr, found = pl.pallas_call(
+        _lookup_kernel,
         grid=(N // block_keys,),
-        in_specs=[
-            pl.BlockSpec((block_keys,), lambda i: (i,)),
-            pl.BlockSpec((nb, spb), lambda i: (0, 0)),   # resident in VMEM
-            pl.BlockSpec((nb, spb), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_keys,), lambda i: (i,)),
-            pl.BlockSpec((block_keys,), lambda i: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32),
-                   jax.ShapeDtypeStruct((N,), jnp.bool_)],
+        in_specs=[key_spec,
+                  pl.BlockSpec((2 * spb, block_keys), lambda i: (0, i))],
+        out_specs=[key_spec, key_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, N), jnp.int32)] * 2,
         interpret=interpret,
-    )(keys, hi, lo)
+    )(keys.reshape(1, N), rows)
+    return ptr[0], found[0].astype(jnp.bool_)

@@ -15,17 +15,15 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.shadow import (MASK24, build_shadow,  # noqa: F401
                                hash32_np, race_lookup_np)
 
+from ..platform import interpret_mode, on_tpu
 from .kernel import race_lookup_fwd
 from .ref import race_lookup_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("use_kernel", "block_keys"))
@@ -35,7 +33,7 @@ def race_lookup(keys, index, *, block_keys: int = 256, use_kernel: bool = True):
     if not use_kernel:
         return race_lookup_ref(keys, index)
     return race_lookup_fwd(keys, index, block_keys=block_keys,
-                           interpret=not _on_tpu())
+                           interpret=interpret_mode())
 
 
 def race_lookup_batch(q: np.ndarray, table: np.ndarray, *,
@@ -53,18 +51,14 @@ def race_lookup_batch(q: np.ndarray, table: np.ndarray, *,
     thousand-client tick cannot afford)."""
     q = np.ascontiguousarray(q, np.uint32)
     if prefer_kernel is None:
-        prefer_kernel = _on_tpu()
-    if prefer_kernel:
-        try:
-            import jax.numpy as jnp
-            n = len(q)
-            pad = -(-max(n, 1) // block_keys) * block_keys - n
-            qp = jnp.asarray(np.concatenate(
-                [q, np.zeros(pad, np.uint32)]).view(np.int32))
-            ptr, found = race_lookup(qp, jnp.asarray(table.view(np.int32)),
-                                     block_keys=block_keys)
-            return (np.asarray(ptr[:n]).view(np.uint32).astype(np.uint32),
-                    np.asarray(found[:n]))
-        except Exception:       # pragma: no cover - jax-less fallback
-            pass
-    return race_lookup_np(q, table)
+        prefer_kernel = on_tpu()
+    if not prefer_kernel:
+        return race_lookup_np(q, table)
+    n = len(q)
+    pad = -(-max(n, 1) // block_keys) * block_keys - n
+    qp = jnp.asarray(np.concatenate([q, np.zeros(pad, np.uint32)])
+                     .view(np.int32))
+    ptr, found = race_lookup(qp, jnp.asarray(table.view(np.int32)),
+                             block_keys=block_keys)
+    return (np.asarray(ptr[:n]).view(np.uint32).astype(np.uint32),
+            np.asarray(found[:n]))

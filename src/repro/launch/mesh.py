@@ -12,24 +12,8 @@ from typing import Optional, Tuple
 import jax
 
 
-def _compat_make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` (and make_mesh's ``axis_types`` kwarg) only
-    exist from jax 0.5.x; on older versions every axis is implicitly Auto,
-    so simply omitting the kwarg is the exact same mesh.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes, **kwargs,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:           # AxisType exists but make_mesh predates it
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
+def _auto(axes):
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(shape: Tuple[int, ...] = (1, 1),
@@ -47,7 +31,7 @@ def make_host_mesh(shape: Tuple[int, ...] = (1, 1),
     for s in shape:
         n *= s
     devs = jax.devices()[:n]
-    return _compat_make_mesh(shape, axes, devices=devs)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes), devices=devs)
 
 
 # TPU v5e hardware constants (roofline targets; per assignment)

@@ -29,18 +29,6 @@ from jax.sharding import PartitionSpec as P
 from .common import ParamBuilder
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: top-level alias from 0.6.x
-    (``check_vma``), the experimental module before that (``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
-
 def make_dense_ffn_params(pb: ParamBuilder, d_model: int, d_ff: int):
     return {
         "w_gate": pb.param((d_model, d_ff), ("fsdp", "mlp")),
@@ -196,7 +184,7 @@ def moe_ffn(ctx: MoEContext, p, x):
             out = jax.lax.psum(out, a)
         return out.reshape(Bl, Sl, D)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh,
         in_specs=(ctx.r_spec, ctx.w_spec, ctx.w_spec, ctx.wd_spec, ctx.x_spec),
         out_specs=ctx.x_spec,

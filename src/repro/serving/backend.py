@@ -23,6 +23,7 @@ from repro.core import codec
 from repro.core.api import KVFuture, Op
 from repro.core.events import FULL, NOT_FOUND, OK, OpResult
 from repro.core.faults import ClientCrashed
+from repro.kernels import leaf_probe_batch
 
 from .kvpool import KVPool, OP_INSERT, OP_UPDATE, PoolConfig
 
@@ -148,7 +149,6 @@ class DeviceBackend:
         validate the candidate window against the device index with one
         batched ``race_lookup`` probe, and return ``[(key32, value),
         ...]`` in key order — the serving twin of core/ordered.py."""
-        from repro.core.ordered import leaf_probe_np
         start = _key32(op.key)
         if op.kind == "scan":
             count, end = int(op.value), None
@@ -157,11 +157,8 @@ class DeviceBackend:
         keys = np.array(sorted(self._keydir), np.uint64)
         if not len(keys):
             return OpResult(OK, value=[])
-        try:                              # Pallas on TPU, numpy elsewhere
-            from repro.kernels import leaf_probe_batch as _probe
-        except Exception:                 # pragma: no cover - jax-less env
-            _probe = leaf_probe_np
-        pos = int(_probe(np.array([start], np.uint64), keys)[0])
+        # Pallas on TPU, numpy elsewhere
+        pos = int(leaf_probe_batch(np.array([start], np.uint64), keys)[0])
         first = pos if (pos >= 0 and int(keys[pos]) >= start) else pos + 1
         cands = keys[first:]
         if end is not None:

@@ -45,6 +45,20 @@ from .snapshot_jax import snapshot_epoch
 OP_INSERT, OP_UPDATE, OP_DELETE = 1, 2, 3
 
 
+def _padded(n: int) -> int:
+    """Device batch size for ``n`` ops: a power of two, at least the probe
+    kernel's 256-key block.  Batch sizes that depend on the data (unique
+    keys of an upsert batch, fingerprint collisions, scan windows) then
+    compile a few shapes instead of one per size."""
+    return max(256, 1 << max(n - 1, 0).bit_length())
+
+
+def _pad(x: np.ndarray, size: int, fill) -> np.ndarray:
+    out = np.full(size, fill, x.dtype)
+    out[:len(x)] = x
+    return out
+
+
 @dataclass
 class PoolConfig:
     n_pages: int = 4096          # pool pages per shard group
@@ -146,25 +160,29 @@ class KVPool:
         paper's single-RDMA_WRITE log embedding).  Chain pointers come from
         the deterministic slab order (pre-positioned)."""
         sl = self._slab(cid)
-        nxt = np.array([sl.free[0] + 1 if sl.free else 0] * len(pages),
-                       np.int32)
-        for i in range(len(pages) - 1):
+        n = len(pages)
+        nxt = np.array([sl.free[0] + 1 if sl.free else 0] * n, np.int32)
+        for i in range(n - 1):
             nxt[i] = pages[i + 1] + 1
         prv = np.concatenate([[sl.last_alloc], pages[:-1] + 1]).astype(np.int32)
-        if len(pages):
+        if n:
             sl.last_alloc = int(pages[-1]) + 1
-        pg = jnp.asarray(pages)
-        entries = jnp.stack([jnp.zeros(len(pages), jnp.int32),
-                             jnp.full((len(pages),), opcode, jnp.int32),
-                             jnp.asarray(keys, jnp.int32),
-                             jnp.ones(len(pages), jnp.int32)], axis=1)
-        self.log = self.log.at[pg].set(entries)
-        self.chain = self.chain.at[pg].set(
-            jnp.stack([jnp.asarray(nxt), jnp.asarray(prv)], axis=1))
+        W = _padded(n)
+        entries = np.zeros((W, 4), np.int32)      # padding rows are dropped
+        entries[:n, 1:] = np.stack([np.full(n, opcode), keys, np.ones(n)], 1)
+        links = np.zeros((W, 2), np.int32)
+        links[:n] = np.stack([nxt, prv], 1)
+        pg = jnp.asarray(_pad(np.asarray(pages, np.int32), W,
+                              self.cfg.n_pages))
+        self.log = self.log.at[pg].set(jnp.asarray(entries), mode="drop")
+        self.chain = self.chain.at[pg].set(jnp.asarray(links), mode="drop")
 
     def free_pages(self, pages: np.ndarray):
         """Any client: set free bits (the RDMA_FAA on the free bitmap)."""
-        self.free_bitmap = self.free_bitmap.at[jnp.asarray(pages)].set(1)
+        pages = np.asarray(pages, np.int32)
+        pg = _pad(pages, _padded(len(pages)), self.cfg.n_pages)
+        self.free_bitmap = self.free_bitmap.at[jnp.asarray(pg)].set(
+            1, mode="drop")
 
     def reclaim(self, cid: int) -> int:
         """Owner-side batched reclaim of freed pages in own chunks (§4.4)."""
@@ -194,11 +212,10 @@ class KVPool:
         self.stats["search_batches"] += 1
         idx2d = self.index[0].reshape(cfg.n_buckets, cfg.slots_per_bucket)
         n = len(keys)
-        pad = -(-n // 256) * 256 - n
-        kp = jnp.asarray(np.concatenate([keys, np.zeros(pad, np.int32)]))
-        ptr, found = race_lookup(kp, idx2d)
-        ptr, found = np.asarray(ptr[:n]), np.asarray(found[:n])
-        page_keys = np.asarray(self.log[jnp.asarray(ptr), 2])
+        kp = jnp.asarray(_pad(np.asarray(keys, np.int32), _padded(n), 0))
+        ptr_d, found_d = race_lookup(kp, idx2d)
+        page_keys = np.asarray(self.log[ptr_d, 2])[:n]
+        ptr, found = np.asarray(ptr_d)[:n], np.asarray(found_d)[:n]
         verified = found & (page_keys == keys)
         shadowed = found & ~verified
         if shadowed.any():
@@ -214,11 +231,12 @@ class KVPool:
     def _search_all_candidates(self, keys: np.ndarray):
         cfg = self.cfg
         spb = cfg.slots_per_bucket
-        kj = jnp.asarray(keys, jnp.int32)
+        n = len(keys)
+        kj = jnp.asarray(_pad(np.asarray(keys, np.int32), _padded(n), 0))
         b1, b2, fp = self._slot_candidates(kj)
         idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-        rows = np.asarray(jnp.concatenate([idx0[b1], idx0[b2]], axis=1))
-        fpv = np.asarray(fp)
+        rows = np.asarray(jnp.concatenate([idx0[b1], idx0[b2]], axis=1))[:n]
+        fpv = np.asarray(fp)[:n]
         log_keys = np.asarray(self.log[:, 2])
         ptr = np.zeros(len(keys), np.int32)
         found = np.zeros(len(keys), bool)
@@ -244,12 +262,13 @@ class KVPool:
         """INSERT/UPDATE a batch of prefix keys -> pages via one (or more)
         SNAPSHOT epochs.  Returns success mask."""
         cfg = self.cfg
-        keys_j = jnp.asarray(keys, jnp.int32)
-        pages_j = jnp.asarray(pages, jnp.int32)
+        n = len(keys)
+        W = _padded(n)
+        keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
+        pages_j = jnp.asarray(_pad(np.asarray(pages, np.int32), W, 0))
         b1, b2, fp = self._slot_candidates(keys_j)
         v_new = SL.pack_slot(fp, pages_j)
-        M = cfg.n_buckets * cfg.slots_per_bucket
-        done = np.zeros(len(keys), bool)
+        done = np.arange(W) >= n                # padding writers never act
         spb = cfg.slots_per_bucket
         for attempt in range(2 * spb):
             # pick a target slot per key: existing fp-match else first empty
@@ -289,13 +308,15 @@ class KVPool:
             done |= np.asarray(res.win)
             if done.all():
                 break
-        return done
+        return done[:n]
 
     def delete_batch(self, cid: int, keys: np.ndarray) -> np.ndarray:
         """DELETE: SNAPSHOT-write slot -> 0 (plus temp log page, elided)."""
         cfg = self.cfg
-        keys_j = jnp.asarray(keys, jnp.int32)
-        ptr, found = self.search(keys)
+        n = len(keys)
+        W = _padded(n)
+        keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
+        _, found = self.search(keys)
         b1, b2, fp = self._slot_candidates(keys_j)
         spb = cfg.slots_per_bucket
         idx0 = self.index[0].reshape(cfg.n_buckets, spb)
@@ -310,7 +331,7 @@ class KVPool:
                                    1)[:, 0]
         v_old = jnp.take_along_axis(rows, jnp.argmax(is_match, 1)[:, None],
                                     1)[:, 0]
-        act = jnp.asarray(found) & is_match.any(1)
+        act = jnp.asarray(_pad(found, W, False)) & is_match.any(1)
         self.key, k = jax.random.split(self.key)
         res = snapshot_epoch(self.index, jnp.where(act, slot, -1), v_old,
                              jnp.zeros_like(v_old), k)
@@ -319,7 +340,7 @@ class KVPool:
         # free the deleted pages (any-client free via bitmap)
         dead = np.asarray(jnp.where(res.win, SL.slot_ptr(v_old), -1))
         self.free_pages(dead[dead >= 0])
-        return np.asarray(res.win)
+        return np.asarray(res.win)[:n]
 
     # ----------------------------------------------------------- recovery --
     def crash_client(self, cid: int):

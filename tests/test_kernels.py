@@ -174,6 +174,39 @@ def test_leaf_probe_batch_entry_point():
     assert got.tolist() == [0, 0, 1, 2, 3, 3, 3]
 
 
+@pytest.mark.parametrize("n_lows", [1, 1000, 2500])
+def test_leaf_probe_batch_kernel_path_matches_numpy(n_lows):
+    """The TPU route of the entry point (here in interpret mode): the
+    fence table padded with the largest key to a power of two, and the
+    count clamped back — exact even for a start equal to that key."""
+    from repro.core.ordered import leaf_probe_np
+    from repro.kernels import leaf_probe_batch
+    rng = np.random.default_rng(n_lows)
+    lows = np.unique(rng.integers(0, 1 << 64, size=n_lows, dtype=np.uint64))
+    lows[-1] = np.uint64(2 ** 64 - 1)
+    starts = np.concatenate([rng.integers(0, 1 << 64, size=61,
+                                          dtype=np.uint64),
+                             lows[:2], np.array([2 ** 64 - 1, 0], np.uint64)])
+    got = leaf_probe_batch(starts, lows, prefer_kernel=True)
+    assert (got == leaf_probe_np(starts, lows)).all()
+
+
+def test_race_lookup_batch_kernel_path_matches_numpy():
+    """The TPU route of the fleet entry point (interpret mode here) on a
+    shadow table, against the numpy mirror, with a ragged batch."""
+    from repro.kernels.race_lookup import (build_shadow, race_lookup_batch,
+                                          race_lookup_np)
+    rng = np.random.default_rng(7)
+    keys = rng.choice(1 << 30, size=700, replace=False).astype(np.uint32)
+    table = build_shadow(keys)
+    q = np.concatenate([keys[:200], rng.integers(0, 1 << 30, size=100)
+                        .astype(np.uint32)])
+    ptr, found = race_lookup_batch(q, table, prefer_kernel=True)
+    ptr_np, found_np = race_lookup_np(q, table)
+    assert (ptr == ptr_np).all() and (found == found_np).all()
+    assert found[:200].mean() > 0.9
+
+
 # ------------------------------------------------------- fleet-tick read --
 @pytest.mark.parametrize("n_verbs,n", [(16, 1), (48, 7), (32, 16)])
 def test_fleet_read_sweep_kernel_matches_numpy(n_verbs, n):
