@@ -93,7 +93,7 @@ def bench_engine_prefix() -> List[Dict]:
                      "finished": len(done), "ticks": eng.steps,
                      "wall_s": time.perf_counter() - t0,
                      "prefix_hits": sum(q.prefix_hits for q in done),
-                     "pool_epochs": eng.pool.stats["epochs"]})
+                     "pool_epochs": eng.health()["epochs"]})
     return rows
 
 

@@ -126,8 +126,8 @@ def elastic_drill():
         fleet.tick()
     ok = sum(f.result().status == OK for _, f in futs)
     print(f" {ok}/{len(futs)} writes OK across both scale-outs, "
-          f"{cluster.migrator.counters['cutovers']} shard cutovers, "
-          f"{cluster.migrator.counters['copied_words']} words copied")
+          f"{cluster.migrator.stats()['cutovers']} shard cutovers, "
+          f"{cluster.migrator.stats()['copied_words']} words copied")
     reader = cluster.store(1)
     lost = [kk for kk, f in futs
             if f.result().status == OK and reader.get(kk) != [kk]]

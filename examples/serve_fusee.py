@@ -39,7 +39,8 @@ def main():
     hits = sum(q.prefix_hits for q in done)
     print(f"prefix-cache: {hits} block hits across requests "
           f"(shared 128-token system prompt = 2 blocks)")
-    print(f"pool: {eng.pool.stats}  replicas converged: "
+    print(f"pool: {eng.pool.metrics.snapshot()['counters']}  "
+          f"replicas converged: "
           f"{eng.pool.check_replicas_converged()}")
     for q in done[:3]:
         print(f"  rid={q.rid} -> {q.out}")

@@ -52,8 +52,7 @@ L008  **bare counters-dict mutation** — protocol/fleet code must not
       or rebinding ``.counters`` to a dict literal): metrics live in the
       typed registry (``repro.obs.registry``) under stable dotted names,
       where snapshots are deterministic, mergeable, and covered by the
-      fused-vs-oracle differential gate.  The surviving ``counters``
-      attributes are read-only deprecation views.
+      fused-vs-oracle differential gate.
 L009  **Python loops in obs hot paths** — the observability package's
       cost contract (obs/flight.py docstring, claims-checked by the
       ``obs_overhead`` bench) is tuple-append per op and array passes per
@@ -386,8 +385,7 @@ class _Linter(ast.NodeVisitor):
     def _check_L008(self, targets, node, *, rebind: bool):
         """Writes through bare ``counters`` dicts in protocol code: the
         typed registry (repro.obs.registry) is the sanctioned metric
-        store; the surviving ``counters`` attributes are read-only
-        deprecation views."""
+        store."""
         if not self.in_core:
             return
         for t in targets:

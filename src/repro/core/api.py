@@ -35,12 +35,13 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import codec
 from . import ordered
 from .events import CRASHED, NOT_FOUND, OK, OpResult
 from .faults import ClientCrashed, OrderedIndexDisabled, SchedulerStalled
-from ..obs.registry import LegacyCounters, Registry, legacy_counters_view
+from ..obs.registry import Registry
 
 __all__ = ["Op", "KVFuture", "KVStore", "SimBackend"]
 
@@ -141,13 +142,15 @@ class KVFuture:
                                       bg_rtts=rec.bg_rtts)
         kind = self.record.kind if self.record is not None else None
         v = res.value
-        if isinstance(v, list) and (kind in ("scan", "range")
-                                    or (v and isinstance(v[0], tuple))):
-            # scan results are [(key, value_words), ...]: decode each
-            # (device futures carry no record, so pair lists self-identify)
-            return dataclasses.replace(res, value=[
-                (k, codec.decode_value(w)) for (k, w) in v])
-        return dataclasses.replace(res, value=codec.decode_value(v))
+        with TraceAnnotation("kv.codec.decode"):
+            if isinstance(v, list) and (kind in ("scan", "range")
+                                        or (v and isinstance(v[0], tuple))):
+                # scan results are [(key, value_words), ...]: decode each
+                # (device futures carry no record, so pair lists
+                # self-identify)
+                return dataclasses.replace(res, value=[
+                    (k, codec.decode_value(w)) for (k, w) in v])
+            return dataclasses.replace(res, value=codec.decode_value(v))
 
 
 # -------------------------------------------------------------- sim backend
@@ -184,8 +187,6 @@ class SimBackend:
         # transient (one per ``cluster.store()`` call), so each carries
         # its own small registry rather than sharing the scheduler's —
         # aggregate across backends with obs.registry.snapshot_merge.
-        # The old ``counters`` dict survives one release as a read-only
-        # deprecation alias (see obs/registry.py).
         self.metrics = Registry()
         self._handles = {
             k: self.metrics.counter("api." + k)
@@ -195,12 +196,6 @@ class SimBackend:
         # memoized shadow index: (cache fingerprint, entries, shadow table)
         self._shadow = (None, None, None)
         self._pump_rr = 0     # rotating QP-lane pick (starvation freedom)
-
-    @property
-    def counters(self) -> LegacyCounters:
-        """Deprecated read-only view of the backend metrics under their
-        historical key names; read ``stats()`` or ``self.metrics``."""
-        return legacy_counters_view("SimBackend", self._handles)
 
     # ------------------------------------------------------------- submit
     def submit_many(self, ops: Sequence[Op], *,

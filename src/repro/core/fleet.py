@@ -44,7 +44,6 @@ from .faults import SchedulerStalled
 from .shadow import build_shadow, hash32_np, race_lookup_np
 from . import sim as sim_module
 from .sim import Scheduler
-from ..obs.registry import LegacyCounters, legacy_counters_view
 
 __all__ = ["FleetEngine"]
 
@@ -80,8 +79,7 @@ class FleetEngine:
         # differential replay oracle.
         self.fused = fused
         # fleet counters live in the scheduler's metrics registry under
-        # "fleet.<name>" dotted names; the old ``counters`` dict survives
-        # one release as a read-only deprecation alias (see obs/registry).
+        # "fleet.<name>" dotted names (``stats()`` reads them)
         reg = scheduler.metrics
         names = ("ticks", "verbs", "array_calls", "master_calls",
                  "index_probe_verbs", "probe_invocations", "probe_keys",
@@ -115,12 +113,6 @@ class FleetEngine:
         self._tp = [0.0, 0.0, 0.0, 0.0]
         self._tp_ticks = 0
         self._fused_tp = (0.0, 0.0)
-
-    @property
-    def counters(self) -> LegacyCounters:
-        """Deprecated read-only view of the fleet metrics under their
-        historical key names; read ``stats()`` or the registry instead."""
-        return legacy_counters_view("FleetEngine", self._handles)
 
     # ------------------------------------------------------------- ticking
     def tick(self) -> int:

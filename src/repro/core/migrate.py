@@ -58,7 +58,6 @@ from . import layout as L
 from .faults import InsufficientReplicas, SchedulerStalled
 from .heap import DMPool
 from .ring import ring_replicas
-from ..obs.registry import LegacyCounters, legacy_counters_view
 
 __all__ = ["MigrationEngine", "RegionMigration"]
 
@@ -105,18 +104,16 @@ class MigrationEngine:
         # checker's enumerated schedule.
         self.manual = False
         # migration counters live in the scheduler's metrics registry
-        # under "migrate.<name>"; the old ``counters`` dict survives one
-        # release as a read-only deprecation alias (see obs/registry.py).
+        # under "migrate.<name>" (``stats()`` reads them)
         self._handles = {
             k: scheduler.metrics.counter("migrate." + k)
             for k in ("migrations", "cutovers", "aborts", "copied_words",
                       "adds", "removes", "retires")}
 
-    @property
-    def counters(self) -> LegacyCounters:
-        """Deprecated read-only view of the migration metrics under their
-        historical key names; read the registry instead."""
-        return legacy_counters_view("MigrationEngine", self._handles)
+    def stats(self) -> Dict[str, int]:
+        """The migration counters (``migrate.<name>`` in the scheduler's
+        registry) under their short names."""
+        return {k: h.value for k, h in self._handles.items()}
 
     # ----------------------------------------------------------- public API
     def add_mn(self) -> int:

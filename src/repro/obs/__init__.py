@@ -7,8 +7,7 @@ substitute.  See README "Observability" for the metric naming contract,
 histogram bucket scheme, and the Perfetto export walkthrough.
 """
 from .registry import (Counter, Gauge, HeatSketch, Histogram,  # noqa: F401
-                       LegacyCounters, PATH_DEPENDENT, Registry, Series,
-                       deterministic_view, legacy_counters_view,
+                       PATH_DEPENDENT, Registry, Series, deterministic_view,
                        snapshot_diff, snapshot_merge)
 from .flight import (ClusterObs, FlightRecorder,  # noqa: F401
                      EV_BEGIN, EV_FAULT, EV_MIG, EV_RECOVERY, EV_REGIME,

@@ -26,20 +26,15 @@ Naming contract: dotted, ``<component>.<metric>[.<dim>.<value>]`` —
 ``op.lat_ticks.kind.insert``, ``op.lat_rtts.mn.3``, ``mn.load``.
 Units ride the name: ``*_ticks`` are scheduler ticks, ``*_rtts`` are
 verb round-trips, ``bytes`` are modeled DM bytes.
-
-The old ad-hoc ``counters`` dicts (api/fleet/migrate) survive one release
-as read-only deprecation aliases: ``LegacyCounters`` is a ``Mapping`` view
-over registry handles under the historical key names.
 """
 from __future__ import annotations
 
-import warnings
-from typing import Dict, Iterable, List, Mapping, Optional, Tuple
+from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
 __all__ = ["Counter", "Gauge", "Histogram", "Series", "HeatSketch",
-           "Registry", "LegacyCounters", "PATH_DEPENDENT",
+           "Registry", "PATH_DEPENDENT",
            "deterministic_view", "snapshot_diff", "snapshot_merge"]
 
 # Metrics whose value depends on HOW a run executed (fused vs oracle
@@ -371,38 +366,3 @@ def snapshot_merge(a: Dict, b: Dict) -> Dict:
                     "rows": sorted(ra + rb, key=lambda r: r[0])}
     return out
 
-
-class LegacyCounters(Mapping):
-    """Read-only dict-view over registry handles under the historical
-    ``counters`` key names.  Deprecated — one release only; read the
-    registry (``cluster.metrics()`` / ``kv.stats()``) instead.  Writes
-    (``counters[k] += 1``) are not supported and flagged by lint L008."""
-
-    __slots__ = ("_handles",)
-
-    def __init__(self, handles: Dict[str, object]):
-        # old key -> Counter/Gauge handle (read .value at access time)
-        self._handles = handles
-
-    def __getitem__(self, key: str):
-        return self._handles[key].value
-
-    def __iter__(self):
-        return iter(self._handles)
-
-    def __len__(self):
-        return len(self._handles)
-
-    def __repr__(self):
-        return f"LegacyCounters({dict(self)!r})"
-
-
-def legacy_counters_view(owner: str, handles: Dict[str, object]
-                         ) -> LegacyCounters:
-    """Build the deprecation alias for one component's old dict, warning
-    on access (Python's default filter dedupes per call site)."""
-    warnings.warn(
-        f"{owner}.counters is deprecated; read the metrics registry "
-        f"(cluster.metrics() / stats()) instead — the dict view will be "
-        f"removed next release", DeprecationWarning, stacklevel=3)
-    return LegacyCounters(handles)

@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import codec
 from repro.core.api import KVFuture, Op
@@ -71,76 +72,85 @@ class DeviceBackend:
 
     def _exec_group(self, kind: str, idxs: List[int], ops, futs):
         if kind == "search":
-            keys = np.array([_key32(ops[i].key) for i in idxs], np.int32)
-            ptr, found = self.pool.search(keys)
-            for n, i in enumerate(idxs):
-                if found[n]:
-                    page = int(ptr[n])
-                    futs[i]._resolve(OpResult(OK, page=page,
-                                              value=self._values.get(page)))
-                else:
-                    futs[i]._resolve(OpResult(NOT_FOUND))
+            with TraceAnnotation("kv.backend.search"):
+                self._search(idxs, ops, futs)
         elif kind in ("insert", "update"):
-            # Duplicate keys within one batch are concurrent upserts of the
-            # same key: exactly one page is written (last writer's value
-            # wins) and every duplicate resolves to that one result — the
-            # pool would otherwise supersede-and-free a page whose OK
-            # future the caller still holds.
-            first: Dict[int, int] = {}      # key32 -> position of its op
-            for n, i in enumerate(idxs):
-                first[_key32(ops[i].key)] = n
-            uniq = sorted(first.values())
-            keys = np.array([_key32(ops[idxs[n]].key) for n in uniq],
-                            np.int32)
-            pages = self.pool.alloc_pages(self.cid, len(uniq))
-            if (pages < 0).any() and self.pool.reclaim(self.cid):
-                # slab ran dry but bitmap-freed pages (superseded upserts,
-                # released surplus) were reclaimable: retry the dead slots
-                dead = pages < 0
-                pages[dead] = self.pool.alloc_pages(self.cid,
-                                                    int(dead.sum()))
-            live = pages >= 0
-            if live.any():
-                opcode = OP_INSERT if kind == "insert" else OP_UPDATE
-                self.pool.write_pages(self.cid, pages[live], keys[live],
-                                      opcode=opcode)
-                ok = self.pool.insert_batch(self.cid, keys[live], pages[live],
-                                            opcode=opcode)
-            else:
-                ok = np.zeros(0, bool)
-            results: Dict[int, OpResult] = {}
-            k = 0
-            for m, n in enumerate(uniq):
-                key = int(keys[m])
-                if not live[m]:
-                    results[key] = OpResult(FULL, page=-1)
-                    continue
-                page = int(pages[m])
-                won = bool(ok[k]); k += 1
-                self._values[page] = codec.encode_value(ops[idxs[n]].value)
-                # keydir superset: even a lost upsert means the KEY is
-                # live (another page won its slot); scans validate
-                self._keydir.add(key)
-                results[key] = OpResult(OK if won else FULL, page=page,
-                                        value=self._values[page])
-            for i in idxs:
-                futs[i]._resolve(results[_key32(ops[i].key)])
+            with TraceAnnotation("kv.backend.upsert"):
+                self._upsert(kind, idxs, ops, futs)
         elif kind == "delete":
-            keys = np.array([_key32(ops[i].key) for i in idxs], np.int32)
-            ok = self.pool.delete_batch(self.cid, keys)
-            for n, i in enumerate(idxs):
-                if ok[n]:
-                    self._keydir.discard(int(keys[n]))
-                futs[i]._resolve(OpResult(OK if ok[n] else NOT_FOUND))
+            with TraceAnnotation("kv.backend.delete"):
+                keys = np.array([_key32(ops[i].key) for i in idxs], np.int32)
+                ok = self.pool.delete_batch(self.cid, keys)
+                for n, i in enumerate(idxs):
+                    if ok[n]:
+                        self._keydir.discard(int(keys[n]))
+                    futs[i]._resolve(OpResult(OK if ok[n] else NOT_FOUND))
         elif kind in ("scan", "range"):
-            for i in idxs:
-                futs[i]._resolve(self._scan_one(ops[i]))
+            with TraceAnnotation("kv.backend.scan"):
+                for i in idxs:
+                    futs[i]._resolve(self._scan_one(ops[i]))
         elif kind == "reclaim":
             n = self.pool.reclaim(self.cid)
             for i in idxs:
                 futs[i]._resolve(OpResult(OK, value=[n]))
         else:
             raise ValueError(kind)
+
+    def _search(self, idxs: List[int], ops, futs):
+        keys = np.array([_key32(ops[i].key) for i in idxs], np.int32)
+        ptr, found = self.pool.search(keys)
+        for n, i in enumerate(idxs):
+            if found[n]:
+                page = int(ptr[n])
+                futs[i]._resolve(OpResult(OK, page=page,
+                                          value=self._values.get(page)))
+            else:
+                futs[i]._resolve(OpResult(NOT_FOUND))
+
+    def _upsert(self, kind: str, idxs: List[int], ops, futs):
+        # Duplicate keys within one batch are concurrent upserts of the
+        # same key: exactly one page is written (last writer's value
+        # wins) and every duplicate resolves to that one result — the
+        # pool would otherwise supersede-and-free a page whose OK
+        # future the caller still holds.
+        first: Dict[int, int] = {}      # key32 -> position of its op
+        for n, i in enumerate(idxs):
+            first[_key32(ops[i].key)] = n
+        uniq = sorted(first.values())
+        keys = np.array([_key32(ops[idxs[n]].key) for n in uniq], np.int32)
+        pages = self.pool.alloc_pages(self.cid, len(uniq))
+        if (pages < 0).any() and self.pool.reclaim(self.cid):
+            # slab ran dry but bitmap-freed pages (superseded upserts,
+            # released surplus) were reclaimable: retry the dead slots
+            dead = pages < 0
+            pages[dead] = self.pool.alloc_pages(self.cid, int(dead.sum()))
+        live = pages >= 0
+        if live.any():
+            opcode = OP_INSERT if kind == "insert" else OP_UPDATE
+            self.pool.write_pages(self.cid, pages[live], keys[live],
+                                  opcode=opcode)
+            ok = self.pool.insert_batch(self.cid, keys[live], pages[live],
+                                        opcode=opcode)
+        else:
+            ok = np.zeros(0, bool)
+        results: Dict[int, OpResult] = {}
+        k = 0
+        for m, n in enumerate(uniq):
+            key = int(keys[m])
+            if not live[m]:
+                results[key] = OpResult(FULL, page=-1)
+                continue
+            page = int(pages[m])
+            won = bool(ok[k]); k += 1
+            with TraceAnnotation("kv.codec.encode"):
+                self._values[page] = codec.encode_value(ops[idxs[n]].value)
+            # keydir superset: even a lost upsert means the KEY is
+            # live (another page won its slot); scans validate
+            self._keydir.add(key)
+            results[key] = OpResult(OK if won else FULL, page=page,
+                                    value=self._values[page])
+        for i in idxs:
+            futs[i]._resolve(results[_key32(ops[i].key)])
 
     # ------------------------------------------------------ ordered scan
     def _scan_one(self, op: Op) -> OpResult:
@@ -197,6 +207,12 @@ class DeviceBackend:
 
     # ---------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:
+        n = {k: self.pool.metrics.counter(f"pool.{k}").value
+             for k in ("alloc.grants", "upsert.attempts", "delete.epochs",
+                       "search.calls")}
         return {"backend": "device", "cid": self.cid, "inflight": 0,
                 "crashed": self.crashed,
-                "pages_valued": len(self._values), **self.pool.stats}
+                "pages_valued": len(self._values),
+                "alloc_rpcs": n["alloc.grants"],
+                "epochs": n["upsert.attempts"] + n["delete.epochs"],
+                "search_batches": n["search.calls"]}

@@ -27,6 +27,14 @@ Components, mapped 1:1 to the paper:
 * Recovery (§5.3): ``recover_client`` re-owns a crashed client's chunks
   from the grant table, walks its allocation-order log chain, reclaims
   incomplete pages, and redoes/commits in-flight index updates.
+
+Observability: every method on the device path opens a fixed-name
+``jax.profiler.TraceAnnotation`` (``kv.pool.*``), so a profiler trace
+splits each call into host dispatch and host waits on the device clock.
+Every device->host read goes through ``_fetch``, which opens the
+``<parent>.wait`` span and counts the reads and their bytes.  Counters
+(``pool.*``) live in ``KVPool.metrics``, a ``repro.obs`` registry, and are
+bumped from host-held values only: no span or counter reads the device.
 """
 from __future__ import annotations
 
@@ -37,12 +45,20 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import race_lookup
+from repro.obs.registry import Registry
 from . import slots_jax as SL
 from .snapshot_jax import snapshot_epoch
 
 OP_INSERT, OP_UPDATE, OP_DELETE = 1, 2, 3
+
+# ``KVPool.metrics`` counters, each under ``pool.<name>``
+COUNTERS = ("search.calls", "search.keys", "search.shadowed",
+            "upsert.calls", "upsert.keys", "upsert.attempts",
+            "upsert.writers", "delete.epochs", "alloc.grants",
+            "host_syncs", "d2h_bytes")
 
 
 def _padded(n: int) -> int:
@@ -101,7 +117,17 @@ class KVPool:
         self.cursor = np.zeros((cfg.n_shards,), np.int32)
         self.slabs: Dict[int, ClientSlab] = {}
         self.key = jax.random.PRNGKey(seed)
-        self.stats = {"alloc_rpcs": 0, "epochs": 0, "search_batches": 0}
+        self.metrics = Registry()
+        self._count = {k: self.metrics.counter("pool." + k) for k in COUNTERS}
+
+    def _fetch(self, span: str, *arrays) -> List[np.ndarray]:
+        """Read device arrays to the host, in order, inside the span
+        ``<span>.wait``: the pool's one place that waits for the device."""
+        with TraceAnnotation(span + ".wait"):
+            out = [np.asarray(a) for a in arrays]
+        self._count["host_syncs"].inc(len(out))
+        self._count["d2h_bytes"].inc(sum(a.nbytes for a in out))
+        return out
 
     # ------------------------------------------------ two-level allocation --
     def _grant_chunk(self, cid: int) -> Optional[int]:
@@ -114,7 +140,7 @@ class KVPool:
             if len(mine):
                 c = int(mine[0])
                 self.grant[c] = cid + 1
-                self.stats["alloc_rpcs"] += 1
+                self._count["alloc.grants"].inc()
                 return c
         return None
 
@@ -140,116 +166,134 @@ class KVPool:
     def alloc_pages(self, cid: int, n: int) -> np.ndarray:
         """Client-side fine allocation of n pages (slab pop; grants chunks
         as needed).  Returns page ids (-1 = pool exhausted)."""
-        sl = self._slab(cid)
-        out = []
-        for _ in range(n):
-            if not sl.free:
-                c = self._grant_chunk(cid)
-                if c is None:
-                    out.append(-1)
-                    continue
-                base = c * self.cfg.chunk_pages
-                sl.free.extend(range(base, base + self.cfg.chunk_pages))
-                sl.chunks.append(c)
-            out.append(sl.free.pop(0))
-        return np.array(out, np.int32)
+        with TraceAnnotation("kv.pool.alloc"):
+            sl = self._slab(cid)
+            out = []
+            for _ in range(n):
+                if not sl.free:
+                    c = self._grant_chunk(cid)
+                    if c is None:
+                        out.append(-1)
+                        continue
+                    base = c * self.cfg.chunk_pages
+                    sl.free.extend(range(base, base + self.cfg.chunk_pages))
+                    sl.chunks.append(c)
+                out.append(sl.free.pop(0))
+            return np.array(out, np.int32)
 
     def write_pages(self, cid: int, pages: np.ndarray, keys: np.ndarray,
                     opcode: int):
         """Write page payload + embedded log entry in ONE device op (the
         paper's single-RDMA_WRITE log embedding).  Chain pointers come from
         the deterministic slab order (pre-positioned)."""
-        sl = self._slab(cid)
-        n = len(pages)
-        nxt = np.array([sl.free[0] + 1 if sl.free else 0] * n, np.int32)
-        for i in range(n - 1):
-            nxt[i] = pages[i + 1] + 1
-        prv = np.concatenate([[sl.last_alloc], pages[:-1] + 1]).astype(np.int32)
-        if n:
-            sl.last_alloc = int(pages[-1]) + 1
-        W = _padded(n)
-        entries = np.zeros((W, 4), np.int32)      # padding rows are dropped
-        entries[:n, 1:] = np.stack([np.full(n, opcode), keys, np.ones(n)], 1)
-        links = np.zeros((W, 2), np.int32)
-        links[:n] = np.stack([nxt, prv], 1)
-        pg = jnp.asarray(_pad(np.asarray(pages, np.int32), W,
-                              self.cfg.n_pages))
-        self.log = self.log.at[pg].set(jnp.asarray(entries), mode="drop")
-        self.chain = self.chain.at[pg].set(jnp.asarray(links), mode="drop")
+        with TraceAnnotation("kv.pool.write"):
+            sl = self._slab(cid)
+            n = len(pages)
+            nxt = np.array([sl.free[0] + 1 if sl.free else 0] * n, np.int32)
+            for i in range(n - 1):
+                nxt[i] = pages[i + 1] + 1
+            prv = np.concatenate([[sl.last_alloc],
+                                  pages[:-1] + 1]).astype(np.int32)
+            if n:
+                sl.last_alloc = int(pages[-1]) + 1
+            W = _padded(n)
+            entries = np.zeros((W, 4), np.int32)  # padding rows are dropped
+            entries[:n, 1:] = np.stack([np.full(n, opcode), keys,
+                                        np.ones(n)], 1)
+            links = np.zeros((W, 2), np.int32)
+            links[:n] = np.stack([nxt, prv], 1)
+            pg = jnp.asarray(_pad(np.asarray(pages, np.int32), W,
+                                  self.cfg.n_pages))
+            self.log = self.log.at[pg].set(jnp.asarray(entries), mode="drop")
+            self.chain = self.chain.at[pg].set(jnp.asarray(links),
+                                               mode="drop")
 
     def free_pages(self, pages: np.ndarray):
         """Any client: set free bits (the RDMA_FAA on the free bitmap)."""
-        pages = np.asarray(pages, np.int32)
-        pg = _pad(pages, _padded(len(pages)), self.cfg.n_pages)
-        self.free_bitmap = self.free_bitmap.at[jnp.asarray(pg)].set(
-            1, mode="drop")
+        with TraceAnnotation("kv.pool.free"):
+            pages = np.asarray(pages, np.int32)
+            pg = _pad(pages, _padded(len(pages)), self.cfg.n_pages)
+            self.free_bitmap = self.free_bitmap.at[jnp.asarray(pg)].set(
+                1, mode="drop")
 
     def reclaim(self, cid: int) -> int:
         """Owner-side batched reclaim of freed pages in own chunks (§4.4)."""
-        sl = self._slab(cid)
-        bm = np.asarray(self.free_bitmap)
-        n = 0
-        for c in sl.chunks:
-            base = c * self.cfg.chunk_pages
-            for p in range(base, base + self.cfg.chunk_pages):
-                if bm[p]:
-                    sl.free.append(p)
-                    n += 1
-        if n:
-            idx = jnp.asarray([p for c in sl.chunks
-                               for p in range(c * self.cfg.chunk_pages,
-                                              (c + 1) * self.cfg.chunk_pages)])
-            self.free_bitmap = self.free_bitmap.at[idx].set(0)
-            self.log = self.log.at[idx, 3].set(0)
-        return n
+        with TraceAnnotation("kv.pool.reclaim"):
+            sl = self._slab(cid)
+            bm, = self._fetch("kv.pool.reclaim", self.free_bitmap)
+            n = 0
+            for c in sl.chunks:
+                base = c * self.cfg.chunk_pages
+                for p in range(base, base + self.cfg.chunk_pages):
+                    if bm[p]:
+                        sl.free.append(p)
+                        n += 1
+            if n:
+                cp = self.cfg.chunk_pages
+                idx = jnp.asarray([p for c in sl.chunks
+                                   for p in range(c * cp, (c + 1) * cp)])
+                self.free_bitmap = self.free_bitmap.at[idx].set(0)
+                self.log = self.log.at[idx, 3].set(0)
+            return n
 
     # -------------------------------------------------------------- index --
     def search(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Batched SEARCH on the primary replica (race_lookup kernel), with
         the RACE data-access integrity check: the key stored on the pointed
         page must match, or the probe is a fingerprint collision -> miss."""
-        cfg = self.cfg
-        self.stats["search_batches"] += 1
-        idx2d = self.index[0].reshape(cfg.n_buckets, cfg.slots_per_bucket)
-        n = len(keys)
-        kp = jnp.asarray(_pad(np.asarray(keys, np.int32), _padded(n), 0))
-        ptr_d, found_d = race_lookup(kp, idx2d)
-        page_keys = np.asarray(self.log[ptr_d, 2])[:n]
-        ptr, found = np.asarray(ptr_d)[:n], np.asarray(found_d)[:n]
-        verified = found & (page_keys == keys)
-        shadowed = found & ~verified
-        if shadowed.any():
-            # collision path (read amplification): probe ALL candidate
-            # slots of both buckets and verify keys, as RACE prescribes
-            p2, f2 = self._search_all_candidates(keys[shadowed])
-            ptr = ptr.copy()
-            ptr[shadowed] = p2
-            verified = verified.copy()
-            verified[shadowed] = f2
-        return np.where(verified, ptr, 0), verified
+        with TraceAnnotation("kv.pool.search"):
+            cfg = self.cfg
+            n = len(keys)
+            self._count["search.calls"].inc()
+            self._count["search.keys"].inc(n)
+            with TraceAnnotation("kv.pool.search.probe"):
+                idx2d = self.index[0].reshape(cfg.n_buckets,
+                                              cfg.slots_per_bucket)
+                kp = jnp.asarray(_pad(np.asarray(keys, np.int32),
+                                      _padded(n), 0))
+                ptr_d, found_d = race_lookup(kp, idx2d)
+                page_keys_d = self.log[ptr_d, 2]
+            page_keys, ptr, found = (a[:n] for a in self._fetch(
+                "kv.pool.search", page_keys_d, ptr_d, found_d))
+            verified = found & (page_keys == keys)
+            shadowed = found & ~verified
+            n_shadowed = int(shadowed.sum())
+            self._count["search.shadowed"].inc(n_shadowed)
+            if n_shadowed:
+                # collision path (read amplification): probe ALL candidate
+                # slots of both buckets and verify keys, as RACE prescribes
+                p2, f2 = self._search_all_candidates(keys[shadowed])
+                ptr = ptr.copy()
+                ptr[shadowed] = p2
+                verified = verified.copy()
+                verified[shadowed] = f2
+            return np.where(verified, ptr, 0), verified
 
     def _search_all_candidates(self, keys: np.ndarray):
-        cfg = self.cfg
-        spb = cfg.slots_per_bucket
-        n = len(keys)
-        kj = jnp.asarray(_pad(np.asarray(keys, np.int32), _padded(n), 0))
-        b1, b2, fp = self._slot_candidates(kj)
-        idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-        rows = np.asarray(jnp.concatenate([idx0[b1], idx0[b2]], axis=1))[:n]
-        fpv = np.asarray(fp)[:n]
-        log_keys = np.asarray(self.log[:, 2])
-        ptr = np.zeros(len(keys), np.int32)
-        found = np.zeros(len(keys), bool)
-        for i in range(len(keys)):
-            for w in rows[i]:
-                if w == 0:
-                    continue
-                if ((int(w) >> 24) & 0xFF) == fpv[i] and \
-                        log_keys[int(w) & 0xFFFFFF] == keys[i]:
-                    ptr[i] = int(w) & 0xFFFFFF
-                    found[i] = True
-                    break
-        return ptr, found
+        with TraceAnnotation("kv.pool.search.collision"):
+            cfg = self.cfg
+            spb = cfg.slots_per_bucket
+            n = len(keys)
+            kj = jnp.asarray(_pad(np.asarray(keys, np.int32), _padded(n), 0))
+            b1, b2, fp = self._slot_candidates(kj)
+            idx0 = self.index[0].reshape(cfg.n_buckets, spb)
+            rows, fpv, log_keys = self._fetch(
+                "kv.pool.search.collision",
+                jnp.concatenate([idx0[b1], idx0[b2]], axis=1), fp,
+                self.log[:, 2])
+            rows, fpv = rows[:n], fpv[:n]
+            ptr = np.zeros(len(keys), np.int32)
+            found = np.zeros(len(keys), bool)
+            for i in range(len(keys)):
+                for w in rows[i]:
+                    if w == 0:
+                        continue
+                    if ((int(w) >> 24) & 0xFF) == fpv[i] and \
+                            log_keys[int(w) & 0xFFFFFF] == keys[i]:
+                        ptr[i] = int(w) & 0xFFFFFF
+                        found[i] = True
+                        break
+            return ptr, found
 
     def _slot_candidates(self, keys: jnp.ndarray):
         cfg = self.cfg
@@ -261,86 +305,111 @@ class KVPool:
                      opcode: int = OP_INSERT) -> np.ndarray:
         """INSERT/UPDATE a batch of prefix keys -> pages via one (or more)
         SNAPSHOT epochs.  Returns success mask."""
-        cfg = self.cfg
-        n = len(keys)
-        W = _padded(n)
-        keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
-        pages_j = jnp.asarray(_pad(np.asarray(pages, np.int32), W, 0))
-        b1, b2, fp = self._slot_candidates(keys_j)
-        v_new = SL.pack_slot(fp, pages_j)
-        done = np.arange(W) >= n                # padding writers never act
-        spb = cfg.slots_per_bucket
-        for attempt in range(2 * spb):
-            # pick a target slot per key: existing fp-match else first empty
-            idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-            rows = jnp.concatenate([idx0[b1], idx0[b2]], axis=1)  # (W, 2spb)
-            offs = jnp.concatenate(
-                [b1[:, None] * spb + jnp.arange(spb)[None],
-                 b2[:, None] * spb + jnp.arange(spb)[None]], axis=1)
-            # fp match alone is not enough: verify the page's key so a
-            # colliding entry is never overwritten (RACE integrity check)
-            page_keys = self.log[SL.slot_ptr(rows), 2]
-            is_match = ((SL.slot_fp(rows) == fp[:, None])
-                        & (rows != 0) & (page_keys == keys_j[:, None]))
-            is_empty = rows == 0
-            cand = jnp.where(is_match.any(1),
-                             jnp.argmax(is_match, 1),
-                             jnp.argmax(is_empty, 1))
-            ok = is_match.any(1) | is_empty.any(1)
-            slot = jnp.take_along_axis(offs, cand[:, None], 1)[:, 0]
-            v_old = jnp.take_along_axis(rows, cand[:, None], 1)[:, 0]
-            act = jnp.asarray(~done) & ok
-            slot_i = jnp.where(act, slot, -1)
-            self.key, k = jax.random.split(self.key)
-            res = snapshot_epoch(self.index, slot_i, v_old, v_new, k)
-            self.index = res.index
-            self.stats["epochs"] += 1
-            # commit logs of winners (old value into the embedded entry)
-            wpg = jnp.where(res.win, pages_j, self.cfg.n_pages)
-            self.log = self.log.at[wpg, 0].set(
-                v_old | jnp.int32(1 << 30), mode="drop")
-            # a winner that overwrote a same-key slot superseded that key's
-            # old page: free it (any-client bitmap free, §4.4) so upserts
-            # don't leak pool capacity
-            superseded = np.asarray(
-                jnp.where(res.win & (v_old != 0), SL.slot_ptr(v_old), -1))
-            self.free_pages(superseded[superseded >= 0])
-            done |= np.asarray(res.win)
-            if done.all():
-                break
-        return done[:n]
+        with TraceAnnotation("kv.pool.upsert"):
+            cfg = self.cfg
+            n = len(keys)
+            self._count["upsert.calls"].inc()
+            self._count["upsert.keys"].inc(n)
+            with TraceAnnotation("kv.pool.upsert.prepare"):
+                W = _padded(n)
+                keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
+                pages_j = jnp.asarray(_pad(np.asarray(pages, np.int32), W, 0))
+                b1, b2, fp = self._slot_candidates(keys_j)
+                v_new = SL.pack_slot(fp, pages_j)
+                done = np.arange(W) >= n        # padding writers never act
+                spb = cfg.slots_per_bucket
+            for attempt in range(2 * spb):
+                with TraceAnnotation("kv.pool.upsert.attempt"):
+                    self._count["upsert.attempts"].inc()
+                    self._count["upsert.writers"].inc(int((~done).sum()))
+                    with TraceAnnotation("kv.pool.upsert.select"):
+                        # pick a target slot per key: existing fp-match
+                        # else first empty
+                        idx0 = self.index[0].reshape(cfg.n_buckets, spb)
+                        rows = jnp.concatenate([idx0[b1], idx0[b2]],
+                                               axis=1)  # (W, 2spb)
+                        offs = jnp.concatenate(
+                            [b1[:, None] * spb + jnp.arange(spb)[None],
+                             b2[:, None] * spb + jnp.arange(spb)[None]],
+                            axis=1)
+                        # fp match alone is not enough: verify the page's
+                        # key so a colliding entry is never overwritten
+                        # (RACE integrity check)
+                        page_keys = self.log[SL.slot_ptr(rows), 2]
+                        is_match = ((SL.slot_fp(rows) == fp[:, None])
+                                    & (rows != 0)
+                                    & (page_keys == keys_j[:, None]))
+                        is_empty = rows == 0
+                        cand = jnp.where(is_match.any(1),
+                                         jnp.argmax(is_match, 1),
+                                         jnp.argmax(is_empty, 1))
+                        ok = is_match.any(1) | is_empty.any(1)
+                        slot = jnp.take_along_axis(offs, cand[:, None],
+                                                   1)[:, 0]
+                        v_old = jnp.take_along_axis(rows, cand[:, None],
+                                                    1)[:, 0]
+                        act = jnp.asarray(~done) & ok
+                        slot_i = jnp.where(act, slot, -1)
+                    with TraceAnnotation("kv.pool.upsert.epoch"):
+                        self.key, k = jax.random.split(self.key)
+                        res = snapshot_epoch(self.index, slot_i, v_old,
+                                             v_new, k)
+                        self.index = res.index
+                        # a winner that overwrote a same-key slot
+                        # superseded that key's old page
+                        superseded_d = jnp.where(res.win & (v_old != 0),
+                                                 SL.slot_ptr(v_old), -1)
+                    superseded, win = self._fetch(
+                        "kv.pool.upsert", superseded_d, res.win)
+                    with TraceAnnotation("kv.pool.upsert.commit"):
+                        # commit logs of winners (old value into the
+                        # embedded entry)
+                        wpg = jnp.where(res.win, pages_j, self.cfg.n_pages)
+                        self.log = self.log.at[wpg, 0].set(
+                            v_old | jnp.int32(1 << 30), mode="drop")
+                        # free superseded pages (any-client bitmap free,
+                        # §4.4) so upserts don't leak pool capacity
+                        self.free_pages(superseded[superseded >= 0])
+                    done |= win
+                    if done.all():
+                        break
+            return done[:n]
 
     def delete_batch(self, cid: int, keys: np.ndarray) -> np.ndarray:
         """DELETE: SNAPSHOT-write slot -> 0 (plus temp log page, elided)."""
-        cfg = self.cfg
-        n = len(keys)
-        W = _padded(n)
-        keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
-        _, found = self.search(keys)
-        b1, b2, fp = self._slot_candidates(keys_j)
-        spb = cfg.slots_per_bucket
-        idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-        rows = jnp.concatenate([idx0[b1], idx0[b2]], axis=1)
-        offs = jnp.concatenate(
-            [b1[:, None] * spb + jnp.arange(spb)[None],
-             b2[:, None] * spb + jnp.arange(spb)[None]], axis=1)
-        page_keys = self.log[SL.slot_ptr(rows), 2]
-        is_match = ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
-                    & (page_keys == keys_j[:, None]))
-        slot = jnp.take_along_axis(offs, jnp.argmax(is_match, 1)[:, None],
-                                   1)[:, 0]
-        v_old = jnp.take_along_axis(rows, jnp.argmax(is_match, 1)[:, None],
-                                    1)[:, 0]
-        act = jnp.asarray(_pad(found, W, False)) & is_match.any(1)
-        self.key, k = jax.random.split(self.key)
-        res = snapshot_epoch(self.index, jnp.where(act, slot, -1), v_old,
-                             jnp.zeros_like(v_old), k)
-        self.index = res.index
-        self.stats["epochs"] += 1
-        # free the deleted pages (any-client free via bitmap)
-        dead = np.asarray(jnp.where(res.win, SL.slot_ptr(v_old), -1))
-        self.free_pages(dead[dead >= 0])
-        return np.asarray(res.win)[:n]
+        with TraceAnnotation("kv.pool.delete"):
+            cfg = self.cfg
+            n = len(keys)
+            W = _padded(n)
+            keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
+            _, found = self.search(keys)
+            b1, b2, fp = self._slot_candidates(keys_j)
+            spb = cfg.slots_per_bucket
+            idx0 = self.index[0].reshape(cfg.n_buckets, spb)
+            rows = jnp.concatenate([idx0[b1], idx0[b2]], axis=1)
+            offs = jnp.concatenate(
+                [b1[:, None] * spb + jnp.arange(spb)[None],
+                 b2[:, None] * spb + jnp.arange(spb)[None]], axis=1)
+            page_keys = self.log[SL.slot_ptr(rows), 2]
+            is_match = ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
+                        & (page_keys == keys_j[:, None]))
+            slot = jnp.take_along_axis(offs, jnp.argmax(is_match, 1)[:, None],
+                                       1)[:, 0]
+            v_old = jnp.take_along_axis(rows,
+                                        jnp.argmax(is_match, 1)[:, None],
+                                        1)[:, 0]
+            act = jnp.asarray(_pad(found, W, False)) & is_match.any(1)
+            self.key, k = jax.random.split(self.key)
+            res = snapshot_epoch(self.index, jnp.where(act, slot, -1), v_old,
+                                 jnp.zeros_like(v_old), k)
+            self.index = res.index
+            self._count["delete.epochs"].inc()
+            # free the deleted pages (any-client free via bitmap)
+            dead, win = self._fetch(
+                "kv.pool.delete",
+                jnp.where(res.win, SL.slot_ptr(v_old), -1), res.win)
+            self.free_pages(dead[dead >= 0])
+            return win[:n]
 
     # ----------------------------------------------------------- recovery --
     def crash_client(self, cid: int):
@@ -351,35 +420,36 @@ class KVPool:
         """§5.3 for the serving pool: re-own chunks from the grant table,
         walk the embedded-log chain, reclaim unused pages, redo uncommitted
         winner index writes."""
-        cfg = self.cfg
-        stats = {"chunks": 0, "used_pages": 0, "reclaimed": 0, "redone": 0}
-        chunks = np.where(self.grant == cid + 1)[0]
-        stats["chunks"] = len(chunks)
-        log = np.asarray(self.log)
-        new_owner = reassign_to if reassign_to is not None else cid
-        sl = self._slab(new_owner)
-        for c in chunks:
-            self.grant[c] = new_owner + 1
-            if c not in sl.chunks:
-                sl.chunks.append(int(c))
-            base = c * cfg.chunk_pages
-            for p in range(base, base + cfg.chunk_pages):
-                used = log[p, 3] & 1
-                if not used:
-                    if p not in sl.free:
-                        sl.free.append(p)
-                    stats["reclaimed"] += 1
-                    continue
-                stats["used_pages"] += 1
-                committed = bool(log[p, 0] & (1 << 30))
-                if not committed and log[p, 1] in (OP_INSERT, OP_UPDATE):
-                    # redo: re-run the index write for this page (§5.3 c1)
-                    ok = self.insert_batch(new_owner,
-                                           np.array([log[p, 2]], np.int32),
-                                           np.array([p], np.int32),
-                                           opcode=int(log[p, 1]))
-                    stats["redone"] += int(ok[0])
-        return stats
+        with TraceAnnotation("kv.pool.recover"):
+            cfg = self.cfg
+            stats = {"chunks": 0, "used_pages": 0, "reclaimed": 0, "redone": 0}
+            chunks = np.where(self.grant == cid + 1)[0]
+            stats["chunks"] = len(chunks)
+            log, = self._fetch("kv.pool.recover", self.log)
+            new_owner = reassign_to if reassign_to is not None else cid
+            sl = self._slab(new_owner)
+            for c in chunks:
+                self.grant[c] = new_owner + 1
+                if c not in sl.chunks:
+                    sl.chunks.append(int(c))
+                base = c * cfg.chunk_pages
+                for p in range(base, base + cfg.chunk_pages):
+                    used = log[p, 3] & 1
+                    if not used:
+                        if p not in sl.free:
+                            sl.free.append(p)
+                        stats["reclaimed"] += 1
+                        continue
+                    stats["used_pages"] += 1
+                    committed = bool(log[p, 0] & (1 << 30))
+                    if not committed and log[p, 1] in (OP_INSERT, OP_UPDATE):
+                        # redo: re-run the index write for this page (§5.3 c1)
+                        ok = self.insert_batch(new_owner,
+                                               np.array([log[p, 2]], np.int32),
+                                               np.array([p], np.int32),
+                                               opcode=int(log[p, 1]))
+                        stats["redone"] += int(ok[0])
+            return stats
 
     # --------------------------------------------------------- invariants --
     def check_replicas_converged(self) -> bool:

@@ -256,7 +256,7 @@ def test_add_mn_under_live_fleet_traffic(seed):
     new_mid = len(cl.pool.mns) - 1
     assert any(new_mid in cl.pool.placement[g]
                for g in cl.pool.index_regions)
-    assert cl.migrator.counters["cutovers"] > 0
+    assert cl.migrator.stats()["cutovers"] > 0
 
 
 def test_add_mn_migration_is_seed_replayable():
@@ -270,7 +270,7 @@ def test_add_mn_migration_is_seed_replayable():
             idx.append(prim.regions[g][:cl.pool.cfg.index_words].tobytes())
         return (tuple(f.result().status for _, f in writes.values()),
                 cl.scheduler.tick, cl.pool.epoch,
-                tuple(sorted(cl.migrator.counters.items())),
+                tuple(sorted(cl.migrator.stats().items())),
                 tuple(idx))
     assert signature(_fleet_ycsb_a_with_add_mn(7)) == \
         signature(_fleet_ycsb_a_with_add_mn(7))
@@ -278,7 +278,7 @@ def test_add_mn_migration_is_seed_replayable():
 
 def test_crash_during_migration_aborts_and_replans():
     cl, writes = _fleet_ycsb_a_with_add_mn(4, crash_mid=1)
-    assert cl.migrator.counters["aborts"] > 0, \
+    assert cl.migrator.stats()["aborts"] > 0, \
         "crash while migrating should abort in-flight windows"
     assert not cl.migrator.busy
     # invariant: acked updates survive the abort + Alg-3 + re-plan chain
@@ -352,7 +352,7 @@ def test_trace_replay_reproduces_migration_run():
                       for r in cl.scheduler.history
                       if r.result is not None),
                 cl.pool.epoch, tuple(shards),
-                tuple(sorted(cl.migrator.counters.items())))
+                tuple(sorted(cl.migrator.stats().items())))
 
     cfg = _cfg(num_mns=2, index_shards=4)
     c1 = FuseeCluster(cfg, num_clients=2, seed=6)

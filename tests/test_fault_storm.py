@@ -201,7 +201,7 @@ def test_membership_churn_storm_is_seed_deterministic(seed):
         return (tuple((t, e.action, e.target) for t, e in injector.fired),
                 tuple((k, c, f.result().status) for k, c, f in futs),
                 rejected, cl.scheduler.tick, cl.pool.epoch,
-                tuple(sorted(cl.migrator.counters.items())), tuple(shards))
+                tuple(sorted(cl.migrator.stats().items())), tuple(shards))
     assert signature(_run_storm(seed, **dict(CHURN))) == \
         signature(_run_storm(seed, **dict(CHURN))), \
         f"(reproduce with FUSEE_STORM_SEEDS={seed})"

@@ -34,7 +34,7 @@ def _counter_signature(fleet):
     """Every engine counter that must not depend on the execution path.
     ``array_calls`` (the fusion's whole point) and the fused/fallback
     tick tallies are intentionally excluded."""
-    c = fleet.counters
+    c = fleet.stats()
     keys = [k for k in c if k.startswith("verbs")] + [
         "ticks", "master_calls", "max_lanes", "index_probe_verbs",
         "ord_leaf_verbs", "probe_invocations", "probe_keys", "probe_hits",
@@ -84,10 +84,10 @@ def _assert_differential(run, *, expect_fused_ticks=True):
                            "mn_bytes", "metrics"), sig_o, sig_f):
         assert a == b, f"fused/oracle divergence in {name}"
     if expect_fused_ticks:
-        assert fl_f.counters["fused_ticks"] > 0
-    assert fl_o.counters["fused_ticks"] == 0
+        assert fl_f.stats()["fused_ticks"] > 0
+    assert fl_o.stats()["fused_ticks"] == 0
     # the fusion must not cost MORE dispatches than the per-kind path
-    assert fl_f.counters["array_calls"] <= fl_o.counters["array_calls"]
+    assert fl_f.stats()["array_calls"] <= fl_o.stats()["array_calls"]
     return cl_o, fl_o, cl_f, fl_f
 
 
@@ -200,7 +200,7 @@ def test_fused_matches_oracle_churn_storm(seed):
     # dual-write windows) and covers crash/recover of clients and MNs —
     # including the loser-reset seeds the model checker pinned
     _cl_o, _fl_o, _cl_f, fl_f = _assert_differential(_mk_storm_runner(seed))
-    assert fl_f.counters["fused_ticks"] > 0
+    assert fl_f.stats()["fused_ticks"] > 0
 
 
 # ------------------------------------------------------------ add_mn midrun
@@ -256,7 +256,7 @@ def _mk_add_mn_runner(seed):
 def test_fused_matches_oracle_add_mn_midrun(seed):
     _cl_o, _fl_o, _cl_f, fl_f = _assert_differential(_mk_add_mn_runner(seed))
     # the dual-write migration window must have forced per-tick fallbacks
-    assert fl_f.counters["fallback_ticks"] > 0
+    assert fl_f.stats()["fallback_ticks"] > 0
 
 
 # ------------------------------------------------- tracer fallback contract
@@ -276,8 +276,8 @@ def test_recording_tracer_forces_fallback_not_drop():
         return cl, fleet
 
     cl_t, fl_t = run(fused=True, trace=True)
-    assert fl_t.counters["fused_ticks"] == 0
-    assert fl_t.counters["fallback_ticks"] > 0
+    assert fl_t.stats()["fused_ticks"] == 0
+    assert fl_t.stats()["fallback_ticks"] > 0
     cl_o, fl_o = run(fused=False, trace=True)
     # identical recorded verb streams: nothing was dropped
     ev_t, ev_o = cl_t.pool._tracer.events(), cl_o.pool._tracer.events()
@@ -286,7 +286,7 @@ def test_recording_tracer_forces_fallback_not_drop():
         assert np.array_equal(ev_t[k], ev_o[k]), k
     # detached tracer: fusing resumes
     cl_d, fl_d = run(fused=True, trace=False)
-    assert fl_d.counters["fused_ticks"] > 0
+    assert fl_d.stats()["fused_ticks"] > 0
     assert _pool_bytes(cl_d) == _pool_bytes(cl_t)
 
 

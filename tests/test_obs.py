@@ -147,16 +147,20 @@ def test_detached_hub_records_nothing_new():
 
 
 def test_legacy_counters_deprecated_but_live():
-    import warnings
+    """The one-release ``counters`` views are gone; ``stats()`` reads the
+    same registry handles, and writing to its dict changes no metric."""
     cl = _seeded_run(1)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        c = cl.fleet().counters
-        assert any(issubclass(x.category, DeprecationWarning) for x in w)
-    # the view reads through the registry handles
-    assert c["ticks"] == cl.scheduler.metrics.get("fleet.ticks").value
-    with pytest.raises(TypeError):
-        c["ticks"] = 5                     # read-only Mapping
+    fleet = cl.fleet()
+    assert not hasattr(fleet, "counters")
+    assert not hasattr(cl.migrator, "counters")
+    c = fleet.stats()
+    ticks = cl.scheduler.metrics.get("fleet.ticks").value
+    assert c["ticks"] == ticks
+    c["ticks"] = 5
+    assert fleet.stats()["ticks"] == ticks
+    snap = cl.metrics()["counters"]
+    mig = cl.migrator.stats()
+    assert mig and mig == {k: snap["migrate." + k] for k in mig}
 
 
 def test_fleet_run_populates_series_and_heat():

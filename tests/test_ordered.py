@@ -382,7 +382,7 @@ def test_scan_across_add_mn_cutover():
         assert [k for k, _ in r.value] == list(range(c * 7, c * 7 + 40))
     # the ordered region was re-homed as a first-class region
     g = cl.pool.ordered_regions[0]
-    assert cl.migrator.counters["cutovers"] >= 1
+    assert cl.migrator.stats()["cutovers"] >= 1
     res = cl.store(0).scan(0, 200)
     assert [k for k, _ in res] == list(range(120))
 
@@ -399,17 +399,17 @@ def test_fleet_locate_wave_single_invocation():
     # warm fences
     fleet.submit_wave([(backends[c], [Op.scan(0, 4)]) for c in range(8)])
     fleet.run()
-    base = fleet.counters["scan_locate_invocations"]
+    base = fleet.stats()["scan_locate_invocations"]
     futs = fleet.submit_wave(
         [(backends[c], [Op.scan(c * 9, 6), Op.scan(c * 3, 2)])
          for c in range(8)])
-    assert fleet.counters["scan_locate_invocations"] == base + 1
-    assert fleet.counters["scan_locate_keys"] >= 16
+    assert fleet.stats()["scan_locate_invocations"] == base + 1
+    assert fleet.stats()["scan_locate_keys"] >= 16
     fleet.run()
     for c, fs in enumerate(futs):
         assert [k for k, _ in fs[0].result().value] == \
             list(range(c * 9, c * 9 + 6))
-    assert fleet.counters["ord_leaf_verbs"] > 0
+    assert fleet.stats()["ord_leaf_verbs"] > 0
 
 
 def test_ycsbe_fleet_same_seed_bit_identical():

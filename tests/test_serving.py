@@ -111,7 +111,7 @@ def test_pool_insert_search_delete(pool):
 def test_pool_two_level_allocation_amortizes_grants(pool):
     pages = pool.alloc_pages(1, 100)
     # 100 pages out of 64-page chunks -> only 2 coarse grants (ALLOC RPCs)
-    assert pool.stats["alloc_rpcs"] == 2
+    assert pool.metrics.counter("pool.alloc.grants").value == 2
     assert len(set(pages.tolist())) == 100
 
 
