@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -57,8 +58,8 @@ OP_INSERT, OP_UPDATE, OP_DELETE = 1, 2, 3
 # ``KVPool.metrics`` counters, each under ``pool.<name>``
 COUNTERS = ("search.calls", "search.keys", "search.shadowed",
             "upsert.calls", "upsert.keys", "upsert.attempts",
-            "upsert.writers", "delete.epochs", "alloc.grants",
-            "host_syncs", "d2h_bytes")
+            "upsert.writers", "upsert.dispatches", "delete.epochs",
+            "alloc.grants", "host_syncs", "d2h_bytes")
 
 
 def _padded(n: int) -> int:
@@ -73,6 +74,62 @@ def _pad(x: np.ndarray, size: int, fill) -> np.ndarray:
     out = np.full(size, fill, x.dtype)
     out[:len(x)] = x
     return out
+
+
+# The write path's device steps: one jitted program each, compiled once per
+# padded batch shape and shared by every pool of one shape.  Padding rows
+# carry page id ``n_pages`` (dropped by the scatters) or never act.
+@jax.jit
+def _write_step(log, chain, pages, entries, links):
+    """Page payload's log entry and chain links, one scatter each."""
+    return (log.at[pages].set(entries, mode="drop"),
+            chain.at[pages].set(links, mode="drop"))
+
+
+@partial(jax.jit, static_argnames=("n_buckets",))
+def _upsert_prepare(keys, pages, n_buckets):
+    """Device copies of the padded keys and pages, each key's bucket pair
+    and fingerprint, and the slot word each writer proposes."""
+    b1, b2 = SL.bucket_pair(keys, n_buckets)
+    fp = SL.fingerprint(keys)
+    return keys, pages, b1, b2, fp, SL.pack_slot(fp, pages)
+
+
+@partial(jax.jit, static_argnames=("slots_per_bucket",))
+def _upsert_select(index, log, keys, b1, b2, fp, pending, rng,
+                   slots_per_bucket):
+    """Target slot per pending writer (its key's fp-match, else the first
+    empty slot of the bucket pair; -1 = none) and the value it expects
+    there, read from the primary; and the next split of the pool's key."""
+    lane = jnp.arange(slots_per_bucket)[None]
+    offs = jnp.concatenate([b1[:, None] * slots_per_bucket + lane,
+                            b2[:, None] * slots_per_bucket + lane], axis=1)
+    rows = index[0, offs]                                  # (W, 2spb)
+    # fp match alone is not enough: verify the page's key so a colliding
+    # entry is never overwritten (RACE integrity check)
+    page_keys = log[SL.slot_ptr(rows), 2]
+    is_match = ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
+                & (page_keys == keys[:, None]))
+    is_empty = rows == 0
+    cand = jnp.where(is_match.any(1), jnp.argmax(is_match, 1),
+                     jnp.argmax(is_empty, 1))[:, None]
+    ok = is_match.any(1) | is_empty.any(1)
+    slot = jnp.take_along_axis(offs, cand, 1)[:, 0]
+    v_old = jnp.take_along_axis(rows, cand, 1)[:, 0]
+    rng, k = jax.random.split(rng)
+    return jnp.where(pending & ok, slot, -1), v_old, rng, k
+
+
+@jax.jit
+def _upsert_commit(log, free_bitmap, win, v_old, pages):
+    """Winners' log commit (old value into the embedded entry), and the
+    free bit of each page a winner superseded (any-client bitmap free,
+    §4.4, as ``KVPool.free_pages``) so upserts don't leak pool capacity."""
+    n_pages = log.shape[0]
+    log = log.at[jnp.where(win, pages, n_pages), 0].set(
+        v_old | jnp.int32(1 << 30), mode="drop")
+    gone = jnp.where(win & (v_old != 0), SL.slot_ptr(v_old), n_pages)
+    return log, free_bitmap.at[gone].set(1, mode="drop")
 
 
 @dataclass
@@ -202,11 +259,10 @@ class KVPool:
                                         np.ones(n)], 1)
             links = np.zeros((W, 2), np.int32)
             links[:n] = np.stack([nxt, prv], 1)
-            pg = jnp.asarray(_pad(np.asarray(pages, np.int32), W,
-                                  self.cfg.n_pages))
-            self.log = self.log.at[pg].set(jnp.asarray(entries), mode="drop")
-            self.chain = self.chain.at[pg].set(jnp.asarray(links),
-                                               mode="drop")
+            pg = _pad(np.asarray(pages, np.int32), W, self.cfg.n_pages)
+            self.log, self.chain = _write_step(self.log, self.chain, pg,
+                                               entries, links)
+            self._count["upsert.dispatches"].inc()
 
     def free_pages(self, pages: np.ndarray):
         """Any client: set free bits (the RDMA_FAA on the free bitmap)."""
@@ -304,7 +360,11 @@ class KVPool:
     def insert_batch(self, cid: int, keys: np.ndarray, pages: np.ndarray,
                      opcode: int = OP_INSERT) -> np.ndarray:
         """INSERT/UPDATE a batch of prefix keys -> pages via one (or more)
-        SNAPSHOT epochs.  Returns success mask."""
+        SNAPSHOT epochs.  Returns success mask.
+
+        Device work: one prepare program, then per attempt the select,
+        ``snapshot_epoch`` and commit programs and one read, of the
+        winners; pending writers are tracked on the host."""
         with TraceAnnotation("kv.pool.upsert"):
             cfg = self.cfg
             n = len(keys)
@@ -312,64 +372,30 @@ class KVPool:
             self._count["upsert.keys"].inc(n)
             with TraceAnnotation("kv.pool.upsert.prepare"):
                 W = _padded(n)
-                keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
-                pages_j = jnp.asarray(_pad(np.asarray(pages, np.int32), W, 0))
-                b1, b2, fp = self._slot_candidates(keys_j)
-                v_new = SL.pack_slot(fp, pages_j)
+                keys_j, pages_j, b1, b2, fp, v_new = _upsert_prepare(
+                    _pad(np.asarray(keys, np.int32), W, 0),
+                    _pad(np.asarray(pages, np.int32), W, 0),
+                    n_buckets=cfg.n_buckets)
+                self._count["upsert.dispatches"].inc()
                 done = np.arange(W) >= n        # padding writers never act
-                spb = cfg.slots_per_bucket
-            for attempt in range(2 * spb):
+            for _ in range(2 * cfg.slots_per_bucket):
                 with TraceAnnotation("kv.pool.upsert.attempt"):
                     self._count["upsert.attempts"].inc()
                     self._count["upsert.writers"].inc(int((~done).sum()))
                     with TraceAnnotation("kv.pool.upsert.select"):
-                        # pick a target slot per key: existing fp-match
-                        # else first empty
-                        idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-                        rows = jnp.concatenate([idx0[b1], idx0[b2]],
-                                               axis=1)  # (W, 2spb)
-                        offs = jnp.concatenate(
-                            [b1[:, None] * spb + jnp.arange(spb)[None],
-                             b2[:, None] * spb + jnp.arange(spb)[None]],
-                            axis=1)
-                        # fp match alone is not enough: verify the page's
-                        # key so a colliding entry is never overwritten
-                        # (RACE integrity check)
-                        page_keys = self.log[SL.slot_ptr(rows), 2]
-                        is_match = ((SL.slot_fp(rows) == fp[:, None])
-                                    & (rows != 0)
-                                    & (page_keys == keys_j[:, None]))
-                        is_empty = rows == 0
-                        cand = jnp.where(is_match.any(1),
-                                         jnp.argmax(is_match, 1),
-                                         jnp.argmax(is_empty, 1))
-                        ok = is_match.any(1) | is_empty.any(1)
-                        slot = jnp.take_along_axis(offs, cand[:, None],
-                                                   1)[:, 0]
-                        v_old = jnp.take_along_axis(rows, cand[:, None],
-                                                    1)[:, 0]
-                        act = jnp.asarray(~done) & ok
-                        slot_i = jnp.where(act, slot, -1)
+                        slot_i, v_old, self.key, k = _upsert_select(
+                            self.index, self.log, keys_j, b1, b2, fp, ~done,
+                            self.key, slots_per_bucket=cfg.slots_per_bucket)
                     with TraceAnnotation("kv.pool.upsert.epoch"):
-                        self.key, k = jax.random.split(self.key)
                         res = snapshot_epoch(self.index, slot_i, v_old,
                                              v_new, k)
                         self.index = res.index
-                        # a winner that overwrote a same-key slot
-                        # superseded that key's old page
-                        superseded_d = jnp.where(res.win & (v_old != 0),
-                                                 SL.slot_ptr(v_old), -1)
-                    superseded, win = self._fetch(
-                        "kv.pool.upsert", superseded_d, res.win)
                     with TraceAnnotation("kv.pool.upsert.commit"):
-                        # commit logs of winners (old value into the
-                        # embedded entry)
-                        wpg = jnp.where(res.win, pages_j, self.cfg.n_pages)
-                        self.log = self.log.at[wpg, 0].set(
-                            v_old | jnp.int32(1 << 30), mode="drop")
-                        # free superseded pages (any-client bitmap free,
-                        # §4.4) so upserts don't leak pool capacity
-                        self.free_pages(superseded[superseded >= 0])
+                        self.log, self.free_bitmap = _upsert_commit(
+                            self.log, self.free_bitmap, res.win, v_old,
+                            pages_j)
+                    self._count["upsert.dispatches"].inc(3)
+                    win, = self._fetch("kv.pool.upsert", res.win)
                     done |= win
                     if done.all():
                         break

@@ -86,7 +86,6 @@ def test_profile_shows_the_spans_nested_as_the_code_is(tmp_path):
         "kv.pool.upsert.epoch": {"kv.pool.upsert.attempt"},
         "kv.pool.upsert.wait": {"kv.pool.upsert.attempt"},
         "kv.pool.upsert.commit": {"kv.pool.upsert.attempt"},
-        "kv.pool.free": {"kv.pool.upsert.commit"},
         "kv.codec.encode": {"kv.backend.upsert"},
         "kv.codec.decode": {None},
         "kv.backend.search": {None},
@@ -102,6 +101,7 @@ def test_profile_shows_the_spans_nested_as_the_code_is(tmp_path):
     # one span a call, or an attempt; the codec's, one an op
     assert count["kv.backend.upsert"] == count["kv.pool.upsert"] == 1
     assert count["kv.pool.upsert.attempt"] == n["pool.upsert.attempts"]
+    assert count["kv.pool.upsert.wait"] == n["pool.upsert.attempts"]
     assert count["kv.pool.search"] == count["kv.pool.search.wait"] == 1
     assert count["kv.pool.search.collision"] == 1
     assert count["kv.codec.encode"] == 64
@@ -158,10 +158,13 @@ def test_counters_match_what_the_code_does(monkeypatch):
     # a GET of every key meets a fingerprint collision
     assert n["pool.search.shadowed"] > 0
     assert seen["collisions"] >= 1
-    # the reads the code makes, and no more: 2 an attempt, 3 a search, 3 a
+    # one device program to write the pages, one to prepare the batch,
+    # then select, epoch and commit in each attempt
+    assert n["pool.upsert.dispatches"] == 1 + 1 + 3 * seen["epochs"]
+    # the reads the code makes, and no more: 1 an attempt, 3 a search, 3 a
     # collision-path call, 1 a reclaim
     assert n["pool.host_syncs"] == seen["reads"] == (
-        2 * seen["epochs"] + 3 * seen["searches"]
+        seen["epochs"] + 3 * seen["searches"]
         + 3 * seen["collisions"] + seen["reclaims"])
     assert n["pool.d2h_bytes"] == seen["bytes"] > 0
 
@@ -173,7 +176,7 @@ def test_delete_counts_its_epoch_and_reads(monkeypatch):
     _call(kv, [Op.delete(k) for k in range(1, 51)])
     n = _diff(pool, before)
     assert n["pool.delete.epochs"] == seen["epochs"] == 1
-    assert n["pool.upsert.attempts"] == 0
+    assert n["pool.upsert.attempts"] == n["pool.upsert.dispatches"] == 0
     # the delete's own search, then the epoch's two reads
     assert n["pool.host_syncs"] == seen["reads"] == (
         3 * seen["searches"] + 3 * seen["collisions"] + 2)

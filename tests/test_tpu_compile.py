@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.leaf_probe.kernel import leaf_probe_fwd
 from repro.kernels.race_lookup.kernel import race_lookup_fwd
+from repro.serving import kvpool
 from repro.serving.snapshot_jax import snapshot_epoch
 
 N_BUCKETS, SPB, REPLICAS, BATCH = 65536, 8, 3, 4096
@@ -75,3 +76,36 @@ def test_snapshot_epoch_compiles_for_v5e(shape):
         shape((2,), jnp.uint32)).compile()
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes >= REPLICAS * N_BUCKETS * SPB * 4
+
+
+# the write path's jitted steps at the largest cell's pool (1M records:
+# 2^20 buckets x 8, 2^21 pages, r = 3), for the window's padded UPDATE
+# batch and the load's
+POOL_BUCKETS, POOL_PAGES = 1 << 20, 1 << 21
+
+
+@pytest.mark.parametrize("step", ["write", "prepare", "select", "commit"])
+@pytest.mark.parametrize("width", [256, 16384])
+def test_write_path_steps_compile_for_v5e(shape, step, width):
+    i32 = jnp.int32
+    log = shape((POOL_PAGES, 4), i32)
+    vec = shape((width,), i32)
+    if step == "write":
+        lowered = kvpool._write_step.lower(
+            log, shape((POOL_PAGES, 2), i32), vec, shape((width, 4), i32),
+            shape((width, 2), i32))
+    elif step == "prepare":
+        lowered = kvpool._upsert_prepare.lower(vec, vec,
+                                               n_buckets=POOL_BUCKETS)
+    elif step == "select":
+        lowered = kvpool._upsert_select.lower(
+            shape((REPLICAS, POOL_BUCKETS * SPB), i32), log, vec, vec, vec,
+            vec, shape((width,), jnp.bool_), shape((2,), jnp.uint32),
+            slots_per_bucket=SPB)
+    else:
+        lowered = kvpool._upsert_commit.lower(
+            log, shape((POOL_PAGES,), jnp.int8), shape((width,), jnp.bool_),
+            vec, vec)
+    mem = lowered.compile().memory_analysis()
+    # no step copies the O(M) index: select reads its rows in place
+    assert mem.temp_size_in_bytes < REPLICAS * POOL_BUCKETS * SPB * 4
