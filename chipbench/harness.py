@@ -22,7 +22,8 @@ A run drives the store's normal entry point, ``KVStore.submit_batch`` over
 2. the window: ticks of the mix for ``seconds``; a tick submits its GETs
    as one batch, then its UPDATEs as one batch, and each call is timed
    from submit until every future's result is in hand and the pool's
-   device arrays are ready;
+   device arrays are ready.  The program's counters are read just before
+   the window opens and just after it closes, never inside a timed call;
 3. the check, after the window: ``reference.Replay`` replays every call
    made since the load began, a read-back of every record goes through
    ``KVStore`` and is checked too, and the index replicas are compared
@@ -39,7 +40,7 @@ import gc
 import importlib.util
 import json
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
@@ -210,6 +211,8 @@ class Readings:
     compiles: int                     # program loads inside the window
     trace: Optional[Reduction]
     peaks: dict
+    # the window's difference of every counter in ``KVPool.metrics``
+    counters: Dict[str, int] = field(default_factory=dict)
 
     def cost(self, kernel: str):
         return load_file(HERE / "costs" / f"{kernel}.py")
@@ -271,6 +274,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         window = gen.stream("window")
         n_before = len(client.calls)
         c0 = compiles[0]
+        counted = _counters(pool)
         spans.on = client.annotate = trace
         t_w0 = time.perf_counter()
         setup_s = t_w0 - t_start
@@ -289,6 +293,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                         break
         window_s = time.perf_counter() - t_w0
         spans.on = client.annotate = False
+        counters = {k: v - counted.get(k, 0)
+                    for k, v in _counters(pool).items()}
         window_compiles = compiles[0] - c0
         stats = dev.memory_stats() or {}
         reduction = Reduction(capture.stop()) if trace else None
@@ -317,7 +323,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         readings = Readings(cell, ops, {k: len(v) for k, v in lat.items()},
                             front_s, spans, epoch_writers, window_compiles,
-                            reduction, peaks)
+                            reduction, peaks, counters)
         for m in cell.per_layer:
             v = load_file(HERE / "layer_metrics" / f"{m['name']}.py").read(
                 readings)
@@ -332,7 +338,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                 metrics[m["name"]] = {"value": v, "unit": unit}
     log(f"window: {window_s!r} s, {attempted} ops, "
         f"{sum(len(v) for v in lat.values())} calls, "
-        f"{window_compiles} program loads inside")
+        f"{window_compiles} program loads inside; the program's counters "
+        f"over it: {json.dumps(counters, sort_keys=True)}")
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
@@ -348,6 +355,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     out["checks"] = {k: {"value": v, "limit": lim}
                      for k, (v, lim) in checks.items()}
     return out
+
+
+def _counters(pool) -> Dict[str, int]:
+    """The program's counters (``KVPool.metrics``), read on the host."""
+    return pool.metrics.snapshot()["counters"]
 
 
 def _annotation(on: bool, name: str):

@@ -1,7 +1,9 @@
 """Host-clock spans that the harness records around calls into the program.
 
-The program emits no span or counter on its device path yet, so the
-harness wraps the calls it wants to see: methods of the run's ``KVPool``
+The program's own spans (``kv.*``) and counters (``KVPool.metrics``) are
+read from the profiler trace (``trace.py``) and around the window
+(``harness.py``).  Beside them the harness wraps the calls that the first
+per-layer metrics time on the host clock: methods of the run's ``KVPool``
 instance and functions of program modules, replaced by attribute for the
 length of one run and put back by ``restore``.  A wrapper records only
 while ``on`` is set (the measured window of a traced run); each recorded

@@ -8,14 +8,18 @@ lists only, so the tests can feed it a small synthetic trace:
     {"devices": [{"name": "/device:TPU:0",
                   "ops": [[name, start_ns, dur_ns], ...],       # XLA Ops
                   "modules": [[name, start_ns, dur_ns], ...]}], # XLA Modules
-     "host": [[name, start_ns, dur_ns], ...]}                   # cb.* spans
+     "host": [[name, start_ns, dur_ns], ...]}           # cb.* and kv.* spans
 
-The window is the host span ``cb.window``.  Busy time is the union of the
-intervals in which an op ran on a device, inside the window, averaged
-over the devices; idle is the rest of the window.  Idle time is put down
-to what the host was doing meanwhile: the innermost ``cb.*`` span open at
-each moment, or ``harness`` where none is (the harness's own work between
-calls).
+Host spans are the harness's own (``cb.*``) and every span the program
+opens (``kv.*``), all on one host thread, so they nest.  The window is the
+host span ``cb.window``.  Busy time is the union of the intervals in which
+an op ran on a device, inside the window, averaged over the devices; idle
+is the rest of the window.  Idle time is put down to what the host was
+doing meanwhile: the innermost span of either family open at each moment,
+or ``harness`` where none is (the harness's own work between calls).  For
+every span name the reduction also gives its total seconds inside the
+window, its self seconds (the total less the spans directly inside it, of
+either family) and its number of calls.
 """
 from __future__ import annotations
 
@@ -31,7 +35,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "cb."
+SPAN_PREFIXES = ("cb.", "kv.")
 WINDOW_SPAN = "cb.window"
 _RUN_ID = re.compile(r"\(\d+\)$")
 
@@ -81,7 +85,7 @@ def read_xspace(path: str) -> dict:
         elif plane.name == HOST_PLANE:
             host += [[e.name, e.start_ns, e.duration_ns]
                      for ln in plane.lines for e in ln.events
-                     if e.name.startswith(SPAN_PREFIX)]
+                     if e.name.startswith(SPAN_PREFIXES)]
     return {"devices": devices, "host": host}
 
 
@@ -128,10 +132,12 @@ class Reduction:
         spans = sorted((e for e in trace["host"] if e[0] != WINDOW_SPAN),
                        key=lambda x: (x[1], -x[2]))
         names, s, e = _arrays(spans)
+        depth, parent = _nesting(s, e)
+        self._span_times(names, s, e, parent)
         cuts = np.unique(np.clip(np.r_[self.t0, self.t1, s, e],
                                  self.t0, self.t1))
         seg_a, seg_b = cuts[:-1], cuts[1:]
-        seg_label = _innermost((seg_a + seg_b) / 2, s, e)
+        seg_label = _innermost((seg_a + seg_b) / 2, s, e, depth)
         labels = names + ["harness"]            # index -1 is "harness"
         busy = 0.0
         idle = np.zeros(len(labels))
@@ -156,6 +162,24 @@ class Reduction:
             if t > 0:
                 self.idle[label] = self.idle.get(label, 0.0) + t / n_dev / 1e9
 
+    def _span_times(self, names, s, e, parent):
+        """Total and self seconds inside the window, and calls, by name."""
+        dur = np.clip(np.minimum(e, self.t1) - np.maximum(s, self.t0),
+                      0.0, None)
+        child = parent >= 0
+        inner = np.bincount(parent[child], weights=dur[child],
+                            minlength=len(s))
+        inside = (e >= self.t0) & (s <= self.t1)
+        self.span_total_s: Dict[str, float] = {}
+        self.span_self_s: Dict[str, float] = {}
+        self.span_calls: Dict[str, int] = {}
+        for i in np.flatnonzero(inside):
+            n = names[i]
+            self.span_total_s[n] = self.span_total_s.get(n, 0.0) + dur[i] / 1e9
+            self.span_self_s[n] = (self.span_self_s.get(n, 0.0)
+                                   + (dur[i] - inner[i]) / 1e9)
+            self.span_calls[n] = self.span_calls.get(n, 0) + 1
+
     def module_seconds(self, prefix: str) -> float:
         return sum(t for n, t in self.modules.items() if n.startswith(prefix))
 
@@ -179,16 +203,27 @@ def _busy_upto(ms: np.ndarray, me: np.ndarray, t: np.ndarray) -> np.ndarray:
     return np.where(k > 0, cum[j] + part, 0.0)
 
 
-def _innermost(t: np.ndarray, s: np.ndarray, e: np.ndarray) -> np.ndarray:
-    """Index of the innermost span open at each ``t``, or -1 where none is.
-    Spans are sorted by start, longest first, and nest (one thread)."""
+def _nesting(s: np.ndarray, e: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Depth of each span, and the index of the span directly around it (-1
+    where none is).  Spans are sorted by start, longest first, and nest
+    (one thread)."""
     depth = np.zeros(len(s), int)
-    stack: List[float] = []
+    parent = np.full(len(s), -1)
+    stack: List[int] = []
     for i in range(len(s)):
-        while stack and stack[-1] <= s[i]:
+        while stack and e[stack[-1]] <= s[i]:
             stack.pop()
         depth[i] = len(stack)
-        stack.append(e[i])
+        if stack:
+            parent[i] = stack[-1]
+        stack.append(i)
+    return depth, parent
+
+
+def _innermost(t: np.ndarray, s: np.ndarray, e: np.ndarray,
+               depth: np.ndarray) -> np.ndarray:
+    """Index of the innermost span open at each ``t``, or -1 where none is
+    (spans as ``_nesting`` takes them, with its depths)."""
     out = np.full(len(t), -1)
     for d in range(int(depth.max(initial=-1)), -1, -1):
         at = np.flatnonzero(depth == d)
