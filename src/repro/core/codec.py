@@ -11,6 +11,13 @@ that word-level substrate:
   ``layout.hash64``, which then derives RACE bucket pair + fingerprint).
   Integer keys pass through unchanged so protocol-level tests and
   benchmarks can still address slots deterministically.
+* key records: the device pool keeps each key whole, as an exact record
+  of ``KEY_WORDS`` int32 words (``key_record``): a tag byte, then the
+  key's bytes (a ``bytes``/``str`` key of at most ``KEY_BYTES_MAX``
+  bytes) or its 64-bit value (an ``int`` key).  The tag is 1 + the length
+  of a byte key and ``INT_KEY_TAG`` for an integer, so ``5`` and
+  ``b"\x05"`` differ and no record is all zero.  A longer byte key is
+  refused, never hashed or cut.
 * values: ``bytes``/``str`` are packed into 8-byte little-endian words
   behind a tagged header word carrying the byte length, so decode can
   recover the exact byte string (including lengths not divisible by 8).
@@ -34,6 +41,9 @@ _MASK64 = (1 << 64) - 1
 VALUE_TAG = 0xB5EE            # 16-bit magic in the header word's top bits
 _TAG_SHIFT = 48
 _LEN_MASK = (1 << 40) - 1     # byte length field (plenty for slab objects)
+KEY_WORDS = 6                 # int32 words of a key record (24 bytes)
+KEY_BYTES_MAX = 4 * KEY_WORDS - 1   # a byte key's most bytes: 23
+INT_KEY_TAG = 0xFF            # a record's tag byte for an integer key
 
 
 class CodecError(TypeError, ValueError):
@@ -57,6 +67,23 @@ def encode_key(key: Key) -> int:
         chunk = int.from_bytes(bytes(key[i:i + 8]), "little")
         h = L.hash64((h ^ chunk) & _MASK64, seed=11)
     return h & _MASK64
+
+
+def key_record(key: Key) -> bytes:
+    """The exact ``4 * KEY_WORDS``-byte record of a user key, as the device
+    pool keeps it beside each page (little-endian int32 words)."""
+    if isinstance(key, int):
+        return (bytes((INT_KEY_TAG,)) + (key & _MASK64).to_bytes(8, "little")
+                + bytes(KEY_BYTES_MAX - 8))
+    if isinstance(key, str):
+        key = key.encode("utf-8")
+    if not isinstance(key, (bytes, bytearray)):
+        raise CodecError(f"key must be bytes/str/int, got {type(key)!r}")
+    if len(key) > KEY_BYTES_MAX:
+        raise CodecError(f"a {len(key)}-byte key is longer than the "
+                         f"{KEY_BYTES_MAX} bytes a device key record holds")
+    return bytes((len(key) + 1,)) + bytes(key) + bytes(KEY_BYTES_MAX
+                                                       - len(key))
 
 
 def encode_value(value: Optional[Value]) -> List[int]:

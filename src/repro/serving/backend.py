@@ -8,10 +8,16 @@ eagerly (device ops are synchronous host calls); the surface is identical
 to the event-level ``SimBackend``, so the engine, benchmarks, and examples
 speak one API for both substrates.
 
-Keys are folded to the pool's 32-bit key space; values (optional, small)
-are retained host-side per page so ``get`` round-trips them.  The page id
-backing a key is reported on ``OpResult.page`` — the serving engine uses
-it for KV-cache page accounting.
+Each key goes to the pool whole: a row of its 31-bit fold, which picks
+the bucket pair and the fingerprint, and its exact record
+(``codec.key_record``: a ``bytes``/``str`` key of at most
+``codec.KEY_BYTES_MAX`` bytes, or an ``int``), which the pool compares on
+the device.  Keys whose folds are equal stay apart.  A longer byte key
+raises ``CodecError`` at submit, before any op of the batch runs.
+Values (optional, small) are retained host-side per page so ``get``
+round-trips them.  The page id backing a key is reported on
+``OpResult.page`` — the serving engine uses it for KV-cache page
+accounting.
 """
 from __future__ import annotations
 
@@ -26,13 +32,25 @@ from repro.core.events import FULL, NOT_FOUND, OK, OpResult
 from repro.core.faults import ClientCrashed
 from repro.kernels import leaf_probe_batch
 
-from .kvpool import KVPool, OP_INSERT, OP_UPDATE, PoolConfig
+from .kvpool import KVPool, OP_INSERT, OP_UPDATE, PoolConfig, fold_ints, \
+    key_rows
+
+_KEYED = ("search", "insert", "update", "delete")
+
+
+def _folds(keys: Sequence) -> np.ndarray:
+    return fold_ints(np.array([codec.encode_key(k) for k in keys],
+                              np.uint64))
 
 
 def _key32(key) -> int:
-    k = codec.encode_key(key)
-    k = (k ^ (k >> 32)) & 0x7FFFFFFF
-    return k if k != 0 else 1
+    return int(_folds([key])[0])
+
+
+def encode_keys(keys: Sequence) -> tuple:
+    """Each key's record (``codec.key_record``), and the pool's key rows."""
+    recs = [codec.key_record(k) for k in keys]
+    return recs, key_rows(_folds(keys), recs)
 
 
 class DeviceBackend:
@@ -46,11 +64,12 @@ class DeviceBackend:
         self.crashed = False                 # set by ServeEngine.crash_worker
         self._values: Dict[int, Any] = {}    # page -> encoded value words
         # ordered keydir twin (core/ordered.py on the sim substrate): the
-        # key32s this backend has upserted, kept as a *superset* of the
-        # live set — scans validate every candidate against the device
-        # index (one batched race_lookup probe), so spurious members are
-        # filtered exactly like stale ordered entries in the simulator
-        self._keydir: set = set()
+        # key records this backend has upserted, each with its fold (the
+        # scan order), kept as a *superset* of the live set — scans
+        # validate every candidate against the device index (one batched
+        # race_lookup probe), so spurious members are filtered exactly
+        # like stale ordered entries in the simulator
+        self._keydir: Dict[bytes, int] = {}
 
     # ------------------------------------------------------------- submit
     def submit_many(self, ops: Sequence[Op]) -> List[KVFuture]:
@@ -58,6 +77,11 @@ class DeviceBackend:
             # same typed error as the event-level substrate: one failure
             # surface across both backends
             raise ClientCrashed(self.cid)
+        with TraceAnnotation("kv.backend.keys"):
+            # every keyed op's row, built once: a key the pool cannot hold
+            # fails the batch before any of it runs
+            recs, rows = encode_keys([op.key if op.kind in _KEYED else 0
+                                      for op in ops])
         futs = [KVFuture(self) for _ in ops]
         # execute maximal same-kind runs as one device batch, preserving
         # cross-kind program order
@@ -66,24 +90,25 @@ class DeviceBackend:
             j = i
             while j < len(ops) and ops[j].kind == ops[i].kind:
                 j += 1
-            self._exec_group(ops[i].kind, list(range(i, j)), ops, futs)
+            self._exec_group(ops[i].kind, list(range(i, j)), ops, futs,
+                             recs[i:j], rows[i:j])
             i = j
         return futs
 
-    def _exec_group(self, kind: str, idxs: List[int], ops, futs):
+    def _exec_group(self, kind: str, idxs: List[int], ops, futs,
+                    recs: List[bytes], rows: np.ndarray):
         if kind == "search":
             with TraceAnnotation("kv.backend.search"):
-                self._search(idxs, ops, futs)
+                self._search(idxs, ops, futs, rows)
         elif kind in ("insert", "update"):
             with TraceAnnotation("kv.backend.upsert"):
-                self._upsert(kind, idxs, ops, futs)
+                self._upsert(kind, idxs, ops, futs, recs, rows)
         elif kind == "delete":
             with TraceAnnotation("kv.backend.delete"):
-                keys = np.array([_key32(ops[i].key) for i in idxs], np.int32)
-                ok = self.pool.delete_batch(self.cid, keys)
+                ok = self.pool.delete_batch(self.cid, rows)
                 for n, i in enumerate(idxs):
                     if ok[n]:
-                        self._keydir.discard(int(keys[n]))
+                        self._keydir.pop(recs[n], None)
                     futs[i]._resolve(OpResult(OK if ok[n] else NOT_FOUND))
         elif kind in ("scan", "range"):
             with TraceAnnotation("kv.backend.scan"):
@@ -96,9 +121,8 @@ class DeviceBackend:
         else:
             raise ValueError(kind)
 
-    def _search(self, idxs: List[int], ops, futs):
-        keys = np.array([_key32(ops[i].key) for i in idxs], np.int32)
-        ptr, found = self.pool.search(keys)
+    def _search(self, idxs: List[int], ops, futs, rows: np.ndarray):
+        ptr, found = self.pool.search(rows)
         for n, i in enumerate(idxs):
             if found[n]:
                 page = int(ptr[n])
@@ -107,17 +131,19 @@ class DeviceBackend:
             else:
                 futs[i]._resolve(OpResult(NOT_FOUND))
 
-    def _upsert(self, kind: str, idxs: List[int], ops, futs):
+    def _upsert(self, kind: str, idxs: List[int], ops, futs,
+                recs: List[bytes], rows: np.ndarray):
         # Duplicate keys within one batch are concurrent upserts of the
         # same key: exactly one page is written (last writer's value
         # wins) and every duplicate resolves to that one result — the
         # pool would otherwise supersede-and-free a page whose OK
-        # future the caller still holds.
-        first: Dict[int, int] = {}      # key32 -> position of its op
-        for n, i in enumerate(idxs):
-            first[_key32(ops[i].key)] = n
-        uniq = sorted(first.values())
-        keys = np.array([_key32(ops[idxs[n]].key) for n in uniq], np.int32)
+        # future the caller still holds.  Keys are the same only where
+        # their records are.
+        last: Dict[bytes, int] = {}     # record -> position of its last op
+        for n, rec in enumerate(recs):
+            last[rec] = n
+        uniq = sorted(last.values())
+        keys = rows[uniq]
         pages = self.pool.alloc_pages(self.cid, len(uniq))
         if (pages < 0).any() and self.pool.reclaim(self.cid):
             # slab ran dry but bitmap-freed pages (superseded upserts,
@@ -133,12 +159,12 @@ class DeviceBackend:
                                         opcode=opcode)
         else:
             ok = np.zeros(0, bool)
-        results: Dict[int, OpResult] = {}
+        results: Dict[bytes, OpResult] = {}
         k = 0
         for m, n in enumerate(uniq):
-            key = int(keys[m])
+            rec = recs[n]
             if not live[m]:
-                results[key] = OpResult(FULL, page=-1)
+                results[rec] = OpResult(FULL, page=-1)
                 continue
             page = int(pages[m])
             won = bool(ok[k]); k += 1
@@ -146,11 +172,11 @@ class DeviceBackend:
                 self._values[page] = codec.encode_value(ops[idxs[n]].value)
             # keydir superset: even a lost upsert means the KEY is
             # live (another page won its slot); scans validate
-            self._keydir.add(key)
-            results[key] = OpResult(OK if won else FULL, page=page,
+            self._keydir[rec] = int(keys[m, 0])
+            results[rec] = OpResult(OK if won else FULL, page=page,
                                     value=self._values[page])
-        for i in idxs:
-            futs[i]._resolve(results[_key32(ops[i].key)])
+        for n, i in enumerate(idxs):
+            futs[i]._resolve(results[recs[n]])
 
     # ------------------------------------------------------ ordered scan
     def _scan_one(self, op: Op) -> OpResult:
@@ -164,24 +190,26 @@ class DeviceBackend:
             count, end = int(op.value), None
         else:
             count, end = None, _key32(op.value)
-        keys = np.array(sorted(self._keydir), np.uint64)
-        if not len(keys):
+        if not self._keydir:
             return OpResult(OK, value=[])
+        entries = sorted(self._keydir.items(), key=lambda e: (e[1], e[0]))
+        folds = np.array([f for _, f in entries], np.uint64)
         # Pallas on TPU, numpy elsewhere
-        pos = int(leaf_probe_batch(np.array([start], np.uint64), keys)[0])
-        first = pos if (pos >= 0 and int(keys[pos]) >= start) else pos + 1
-        cands = keys[first:]
-        if end is not None:
-            cands = cands[cands < np.uint64(end)]
+        pos = int(leaf_probe_batch(np.array([start], np.uint64), folds)[0])
+        first = pos if (pos >= 0 and int(folds[pos]) >= start) else pos + 1
+        if first < len(folds):      # the first of keys with an equal fold
+            first = int(np.searchsorted(folds, folds[first]))
+        last = (len(folds) if end is None else
+                int(np.searchsorted(folds, np.uint64(end))))
         out: list = []
-        i = 0
-        while i < len(cands) and (count is None or len(out) < count):
-            window = cands[i:i + max(2 * (count or 64), 64)]
-            ptr, found = self.pool.search(window.astype(np.int64)
-                                          .astype(np.int32))
-            for n, k in enumerate(window.tolist()):
+        i = first
+        while i < last and (count is None or len(out) < count):
+            window = entries[i:min(last, i + max(2 * (count or 64), 64))]
+            ptr, found = self.pool.search(key_rows(
+                [f for _, f in window], [r for r, _ in window]))
+            for n, (_, fold) in enumerate(window):
                 if found[n]:
-                    out.append((int(k), self._values.get(int(ptr[n]))))
+                    out.append((fold, self._values.get(int(ptr[n]))))
                     if count is not None and len(out) >= count:
                         break
             i += len(window)

@@ -21,9 +21,16 @@ Components, mapped 1:1 to the paper:
   pages out of their chunks with local free lists (slab).  Frees set bits
   in a per-chunk free bitmap (FAA analog); owners reclaim in batches.
 * Embedded operation log (§4.5): every page carries a log record
-  (old slot value, opcode, key, used/invalid bits) written together with
-  the page payload; per-client allocation order forms the recovery chain
-  (next/prev pointers pre-positioned from the deterministic free list).
+  (old slot value, opcode, key fold, used/invalid bits) and its key's
+  exact record (``page_keys``), written together with the page payload;
+  per-client allocation order forms the recovery chain (next/prev
+  pointers pre-positioned from the deterministic free list).
+* Keys (RACE's data-access integrity check): a key is a row of
+  ``KEY_COLS`` int32 words, its 31-bit fold and then its exact record
+  (``codec.key_record``).  The fold picks the bucket pair and the
+  fingerprint; a slot is a key's only where ``slot_match`` finds the
+  key's own record on the page it points at.  Every lookup, upsert,
+  delete and recovery redo goes through that one rule on the device.
 * Recovery (§5.3): ``recover_client`` re-owns a crashed client's chunks
   from the grant table, walks its allocation-order log chain, reclaims
   incomplete pages, and redoes/commits in-flight index updates.
@@ -48,18 +55,54 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.core import codec
 from repro.kernels import race_lookup
 from repro.obs.registry import Registry
 from . import slots_jax as SL
 from .snapshot_jax import snapshot_epoch
 
 OP_INSERT, OP_UPDATE, OP_DELETE = 1, 2, 3
+KEY_COLS = 1 + codec.KEY_WORDS          # a key row: fold, then record
 
 # ``KVPool.metrics`` counters, each under ``pool.<name>``
 COUNTERS = ("search.calls", "search.keys", "search.shadowed",
-            "upsert.calls", "upsert.keys", "upsert.attempts",
-            "upsert.writers", "upsert.dispatches", "delete.epochs",
-            "alloc.grants", "host_syncs", "d2h_bytes")
+            "search.aliased", "upsert.calls", "upsert.keys",
+            "upsert.attempts", "upsert.writers", "upsert.dispatches",
+            "delete.epochs", "alloc.grants", "host_syncs", "d2h_bytes",
+            "h2d_key_bytes")
+
+# flag bits of the one word a lookup reads back per key, above the page
+# pointer's 24 bits
+_FOUND, _SHADOWED, _ALIASED = 1 << 24, 1 << 25, 1 << 26
+
+
+def fold_ints(keys64) -> np.ndarray:
+    """The 31-bit fold of each 64-bit protocol key (``codec.encode_key``):
+    its halves xor-ed, 0 taken to 1.  It picks the bucket pair and the
+    fingerprint, nothing else."""
+    v = np.asarray(keys64).astype(np.int64).astype(np.uint64)
+    f = ((v ^ (v >> np.uint64(32))) & np.uint64(0x7FFFFFFF)).astype(np.int32)
+    return np.where(f == 0, 1, f).astype(np.int32)
+
+
+def key_rows(folds, recs) -> np.ndarray:
+    """The pool's key rows, (n, KEY_COLS) int32, of keys' folds and their
+    records (``codec.key_record``, bytes)."""
+    rows = np.empty((len(recs), KEY_COLS), np.int32)
+    rows[:, 0] = folds
+    rows[:, 1:] = np.frombuffer(b"".join(recs), "<i4").reshape(
+        len(recs), codec.KEY_WORDS)
+    return rows
+
+
+def as_key_rows(keys) -> np.ndarray:
+    """Key rows as the pool's methods take them: (n, KEY_COLS) rows as
+    they are, or a 1-D integer array, a batch of integer keys."""
+    keys = np.asarray(keys)
+    if keys.ndim == 2:
+        return keys.astype(np.int32, copy=False)
+    return key_rows(fold_ints(keys),
+                    [codec.key_record(int(k)) for k in keys.tolist()])
 
 
 def _padded(n: int) -> int:
@@ -76,40 +119,94 @@ def _pad(x: np.ndarray, size: int, fill) -> np.ndarray:
     return out
 
 
-# The write path's device steps: one jitted program each, compiled once per
-# padded batch shape and shared by every pool of one shape.  Padding rows
-# carry page id ``n_pages`` (dropped by the scatters) or never act.
+# The device steps: one jitted program each, compiled once per padded
+# batch shape and shared by every pool of one shape.  Padding rows carry
+# page id ``n_pages`` (dropped by the scatters) or never act.
 @jax.jit
-def _write_step(log, chain, pages, entries, links):
-    """Page payload's log entry and chain links, one scatter each."""
-    return (log.at[pages].set(entries, mode="drop"),
-            chain.at[pages].set(links, mode="drop"))
+def slot_match(rows, fp, page_keys, recs):
+    """The RACE slot-match rule, the one place it is written: slot words
+    ``rows`` (W, C) of keys with fingerprints ``fp`` (W,) and records
+    ``recs`` (W, KEY_WORDS) are the key's where the fingerprint matches,
+    the slot is in use, and the page it points at holds the key's own
+    record (a fingerprint or fold match alone is a collision)."""
+    held = page_keys[SL.slot_ptr(rows)]                    # (W, C, words)
+    return ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
+            & (held == recs[:, None, :]).all(-1))
 
 
-@partial(jax.jit, static_argnames=("n_buckets",))
-def _upsert_prepare(keys, pages, n_buckets):
-    """Device copies of the padded keys and pages, each key's bucket pair
-    and fingerprint, and the slot word each writer proposes."""
-    b1, b2 = SL.bucket_pair(keys, n_buckets)
-    fp = SL.fingerprint(keys)
-    return keys, pages, b1, b2, fp, SL.pack_slot(fp, pages)
-
-
-@partial(jax.jit, static_argnames=("slots_per_bucket",))
-def _upsert_select(index, log, keys, b1, b2, fp, pending, rng,
-                   slots_per_bucket):
-    """Target slot per pending writer (its key's fp-match, else the first
-    empty slot of the bucket pair; -1 = none) and the value it expects
-    there, read from the primary; and the next split of the pool's key."""
+def _candidates(index, keys, slots_per_bucket):
+    """Offsets and words of the slots of both buckets of each key row,
+    read from the primary, and each key's fingerprint."""
+    n_buckets = index.shape[1] // slots_per_bucket
+    b1, b2 = SL.bucket_pair(keys[:, 0], n_buckets)
     lane = jnp.arange(slots_per_bucket)[None]
     offs = jnp.concatenate([b1[:, None] * slots_per_bucket + lane,
                             b2[:, None] * slots_per_bucket + lane], axis=1)
-    rows = index[0, offs]                                  # (W, 2spb)
-    # fp match alone is not enough: verify the page's key so a colliding
-    # entry is never overwritten (RACE integrity check)
-    page_keys = log[SL.slot_ptr(rows), 2]
-    is_match = ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
-                & (page_keys == keys[:, None]))
+    return offs, index[0, offs], SL.fingerprint(keys[:, 0])
+
+
+def _aliased(rows, fp, log, keys, is_match):
+    """Keys with a slot that the fold alone would take for theirs: its
+    fingerprint matches and its page holds the key's fold, but another
+    key's record."""
+    same_fold = log[SL.slot_ptr(rows), 2] == keys[:, :1]
+    return ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0) & same_fold
+            & ~is_match).any(1)
+
+
+@jax.jit
+def _write_step(log, chain, page_keys, pages, entries, links, recs):
+    """Page payload's log entry, chain links and key record, one scatter
+    each."""
+    return (log.at[pages].set(entries, mode="drop"),
+            chain.at[pages].set(links, mode="drop"),
+            page_keys.at[pages].set(recs, mode="drop"))
+
+
+@jax.jit
+def _search_check(log, page_keys, keys, ptr, found):
+    """The integrity check of each probed slot: one word a key, the page
+    pointer of a verified key (0 otherwise) and the flags ``_FOUND``,
+    ``_SHADOWED`` (an fp match on another key's page) and ``_ALIASED``
+    (that page holds the key's fold)."""
+    fp = SL.fingerprint(keys[:, 0])
+    probed = jnp.where(found, SL.pack_slot(fp, ptr), 0)[:, None]
+    verified = slot_match(probed, fp, page_keys, keys[:, 1:])[:, 0]
+    shadowed = found & ~verified
+    aliased = _aliased(probed, fp, log, keys, verified[:, None])
+    return (jnp.where(verified, ptr | _FOUND, 0)
+            | jnp.where(shadowed, _SHADOWED, 0)
+            | jnp.where(aliased, _ALIASED, 0))
+
+
+@partial(jax.jit, static_argnames=("slots_per_bucket",))
+def _search_all_check(index, log, page_keys, keys, slots_per_bucket):
+    """RACE's collision path: every candidate slot of both buckets of
+    each key row checked by ``slot_match``; one word a key, as
+    ``_search_check`` gives it (no ``_SHADOWED``)."""
+    _, rows, fp = _candidates(index, keys, slots_per_bucket)
+    is_match = slot_match(rows, fp, page_keys, keys[:, 1:])
+    found = is_match.any(1)
+    hit = jnp.take_along_axis(rows, jnp.argmax(is_match, 1)[:, None], 1)
+    return (jnp.where(found, SL.slot_ptr(hit[:, 0]) | _FOUND, 0)
+            | jnp.where(_aliased(rows, fp, log, keys, is_match), _ALIASED, 0))
+
+
+@jax.jit
+def _upsert_prepare(keys, pages):
+    """Device copies of the padded key rows and pages, and the slot word
+    each writer proposes."""
+    return keys, pages, SL.pack_slot(SL.fingerprint(keys[:, 0]), pages)
+
+
+@partial(jax.jit, static_argnames=("slots_per_bucket",))
+def _upsert_select(index, page_keys, keys, pending, rng, slots_per_bucket):
+    """Target slot per pending writer (its key's slot, else the first
+    empty slot of the bucket pair; -1 = none) and the value it expects
+    there, read from the primary; and the next split of the pool's key."""
+    offs, rows, fp = _candidates(index, keys, slots_per_bucket)
+    # a colliding entry is never overwritten (RACE integrity check)
+    is_match = slot_match(rows, fp, page_keys, keys[:, 1:])
     is_empty = rows == 0
     cand = jnp.where(is_match.any(1), jnp.argmax(is_match, 1),
                      jnp.argmax(is_empty, 1))[:, None]
@@ -153,7 +250,8 @@ class ClientSlab:
 class KVPool:
     """Host-coordinated, device-resident FUSEE pool.
 
-    Device state (jnp): index replicas, page log, free bitmap.
+    Device state (jnp): index replicas, page log, page key records,
+    allocation chain, free bitmap.
     Host state (np): grant table cursor per shard, per-client slabs —
     exactly the split the paper prescribes (coarse state at MNs, fine state
     at clients)."""
@@ -162,8 +260,10 @@ class KVPool:
         self.cfg = cfg
         M = cfg.n_buckets * cfg.slots_per_bucket
         self.index = jnp.zeros((cfg.replicas, M), jnp.int32)
-        # page log: [old_value, opcode, key, flags(used|invalid<<1)]
+        # page log: [old_value, opcode, key fold, flags(used|invalid<<1)]
         self.log = jnp.zeros((cfg.n_pages, 4), jnp.int32)
+        # each page's key record (codec.key_record), written with its log
+        self.page_keys = jnp.zeros((cfg.n_pages, codec.KEY_WORDS), jnp.int32)
         # next/prev allocation-order chain per page (+1; 0 = nil)
         self.chain = jnp.zeros((cfg.n_pages, 2), jnp.int32)
         self.free_bitmap = jnp.zeros((cfg.n_pages,), jnp.int8)
@@ -184,6 +284,14 @@ class KVPool:
             out = [np.asarray(a) for a in arrays]
         self._count["host_syncs"].inc(len(out))
         self._count["d2h_bytes"].inc(sum(a.nbytes for a in out))
+        return out
+
+    def _keys_out(self, rows: np.ndarray, width: int) -> np.ndarray:
+        """Key rows padded to ``width`` rows of zeros, to go to the
+        device; counts their bytes (``pool.h2d_key_bytes``)."""
+        out = np.zeros((width, KEY_COLS), np.int32)
+        out[:len(rows)] = rows
+        self._count["h2d_key_bytes"].inc(out.nbytes)
         return out
 
     # ------------------------------------------------ two-level allocation --
@@ -240,10 +348,11 @@ class KVPool:
 
     def write_pages(self, cid: int, pages: np.ndarray, keys: np.ndarray,
                     opcode: int):
-        """Write page payload + embedded log entry in ONE device op (the
-        paper's single-RDMA_WRITE log embedding).  Chain pointers come from
-        the deterministic slab order (pre-positioned)."""
+        """Write page payload + embedded log entry + key record in ONE
+        device op (the paper's single-RDMA_WRITE log embedding).  Chain
+        pointers come from the deterministic slab order (pre-positioned)."""
         with TraceAnnotation("kv.pool.write"):
+            keys = as_key_rows(keys)
             sl = self._slab(cid)
             n = len(pages)
             nxt = np.array([sl.free[0] + 1 if sl.free else 0] * n, np.int32)
@@ -255,13 +364,14 @@ class KVPool:
                 sl.last_alloc = int(pages[-1]) + 1
             W = _padded(n)
             entries = np.zeros((W, 4), np.int32)  # padding rows are dropped
-            entries[:n, 1:] = np.stack([np.full(n, opcode), keys,
+            entries[:n, 1:] = np.stack([np.full(n, opcode), keys[:, 0],
                                         np.ones(n)], 1)
             links = np.zeros((W, 2), np.int32)
             links[:n] = np.stack([nxt, prv], 1)
             pg = _pad(np.asarray(pages, np.int32), W, self.cfg.n_pages)
-            self.log, self.chain = _write_step(self.log, self.chain, pg,
-                                               entries, links)
+            self.log, self.chain, self.page_keys = _write_step(
+                self.log, self.chain, self.page_keys, pg, entries, links,
+                self._keys_out(keys, W)[:, 1:])
             self._count["upsert.dispatches"].inc()
 
     def free_pages(self, pages: np.ndarray):
@@ -295,67 +405,52 @@ class KVPool:
     # -------------------------------------------------------------- index --
     def search(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Batched SEARCH on the primary replica (race_lookup kernel), with
-        the RACE data-access integrity check: the key stored on the pointed
-        page must match, or the probe is a fingerprint collision -> miss."""
+        the RACE data-access integrity check on the device: the page a
+        probe points at must hold the key's own record, or the probe is a
+        collision and the key's every candidate slot is checked.  Returns
+        host (ptr, found), one each per key row."""
         with TraceAnnotation("kv.pool.search"):
             cfg = self.cfg
+            keys = as_key_rows(keys)
             n = len(keys)
             self._count["search.calls"].inc()
             self._count["search.keys"].inc(n)
             with TraceAnnotation("kv.pool.search.probe"):
                 idx2d = self.index[0].reshape(cfg.n_buckets,
                                               cfg.slots_per_bucket)
-                kp = jnp.asarray(_pad(np.asarray(keys, np.int32),
-                                      _padded(n), 0))
-                ptr_d, found_d = race_lookup(kp, idx2d)
-                page_keys_d = self.log[ptr_d, 2]
-            page_keys, ptr, found = (a[:n] for a in self._fetch(
-                "kv.pool.search", page_keys_d, ptr_d, found_d))
-            verified = found & (page_keys == keys)
-            shadowed = found & ~verified
+                kp = self._keys_out(keys, _padded(n))
+                fold = np.ascontiguousarray(kp[:, 0])      # the probe's keys
+                self._count["h2d_key_bytes"].inc(fold.nbytes)
+                ptr_d, found_d = race_lookup(fold, idx2d)
+            with TraceAnnotation("kv.pool.search.check"):
+                word_d = _search_check(self.log, self.page_keys, kp, ptr_d,
+                                       found_d)
+            word, = self._fetch("kv.pool.search", word_d)
+            word = word[:n]
+            shadowed = (word & _SHADOWED) != 0
             n_shadowed = int(shadowed.sum())
             self._count["search.shadowed"].inc(n_shadowed)
             if n_shadowed:
-                # collision path (read amplification): probe ALL candidate
-                # slots of both buckets and verify keys, as RACE prescribes
-                p2, f2 = self._search_all_candidates(keys[shadowed])
-                ptr = ptr.copy()
-                ptr[shadowed] = p2
-                verified = verified.copy()
-                verified[shadowed] = f2
-            return np.where(verified, ptr, 0), verified
+                # collision path (read amplification): check ALL candidate
+                # slots of both buckets, as RACE prescribes
+                word = word.copy()
+                word[shadowed] = (self._search_all_candidates(keys[shadowed])
+                                  | (word[shadowed] & _ALIASED))
+            self._count["search.aliased"].inc(
+                int(((word & _ALIASED) != 0).sum()))
+            return word & SL.MASK24, (word & _FOUND) != 0
 
-    def _search_all_candidates(self, keys: np.ndarray):
+    def _search_all_candidates(self, keys: np.ndarray) -> np.ndarray:
+        """The collision path for key rows: one word a key, as
+        ``_search_all_check`` gives it."""
         with TraceAnnotation("kv.pool.search.collision"):
-            cfg = self.cfg
-            spb = cfg.slots_per_bucket
             n = len(keys)
-            kj = jnp.asarray(_pad(np.asarray(keys, np.int32), _padded(n), 0))
-            b1, b2, fp = self._slot_candidates(kj)
-            idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-            rows, fpv, log_keys = self._fetch(
+            word, = self._fetch(
                 "kv.pool.search.collision",
-                jnp.concatenate([idx0[b1], idx0[b2]], axis=1), fp,
-                self.log[:, 2])
-            rows, fpv = rows[:n], fpv[:n]
-            ptr = np.zeros(len(keys), np.int32)
-            found = np.zeros(len(keys), bool)
-            for i in range(len(keys)):
-                for w in rows[i]:
-                    if w == 0:
-                        continue
-                    if ((int(w) >> 24) & 0xFF) == fpv[i] and \
-                            log_keys[int(w) & 0xFFFFFF] == keys[i]:
-                        ptr[i] = int(w) & 0xFFFFFF
-                        found[i] = True
-                        break
-            return ptr, found
-
-    def _slot_candidates(self, keys: jnp.ndarray):
-        cfg = self.cfg
-        b1, b2 = SL.bucket_pair(keys, cfg.n_buckets)
-        fp = SL.fingerprint(keys)
-        return b1, b2, fp
+                _search_all_check(self.index, self.log, self.page_keys,
+                                  self._keys_out(keys, _padded(n)),
+                                  slots_per_bucket=self.cfg.slots_per_bucket))
+            return word[:n]
 
     def insert_batch(self, cid: int, keys: np.ndarray, pages: np.ndarray,
                      opcode: int = OP_INSERT) -> np.ndarray:
@@ -367,15 +462,15 @@ class KVPool:
         winners; pending writers are tracked on the host."""
         with TraceAnnotation("kv.pool.upsert"):
             cfg = self.cfg
+            keys = as_key_rows(keys)
             n = len(keys)
             self._count["upsert.calls"].inc()
             self._count["upsert.keys"].inc(n)
             with TraceAnnotation("kv.pool.upsert.prepare"):
                 W = _padded(n)
-                keys_j, pages_j, b1, b2, fp, v_new = _upsert_prepare(
-                    _pad(np.asarray(keys, np.int32), W, 0),
-                    _pad(np.asarray(pages, np.int32), W, 0),
-                    n_buckets=cfg.n_buckets)
+                keys_j, pages_j, v_new = _upsert_prepare(
+                    self._keys_out(keys, W),
+                    _pad(np.asarray(pages, np.int32), W, 0))
                 self._count["upsert.dispatches"].inc()
                 done = np.arange(W) >= n        # padding writers never act
             for _ in range(2 * cfg.slots_per_bucket):
@@ -384,7 +479,7 @@ class KVPool:
                     self._count["upsert.writers"].inc(int((~done).sum()))
                     with TraceAnnotation("kv.pool.upsert.select"):
                         slot_i, v_old, self.key, k = _upsert_select(
-                            self.index, self.log, keys_j, b1, b2, fp, ~done,
+                            self.index, self.page_keys, keys_j, ~done,
                             self.key, slots_per_bucket=cfg.slots_per_bucket)
                     with TraceAnnotation("kv.pool.upsert.epoch"):
                         res = snapshot_epoch(self.index, slot_i, v_old,
@@ -402,31 +497,20 @@ class KVPool:
             return done[:n]
 
     def delete_batch(self, cid: int, keys: np.ndarray) -> np.ndarray:
-        """DELETE: SNAPSHOT-write slot -> 0 (plus temp log page, elided)."""
+        """DELETE: SNAPSHOT-write each found key's slot -> 0 (plus temp log
+        page, elided)."""
         with TraceAnnotation("kv.pool.delete"):
-            cfg = self.cfg
+            keys = as_key_rows(keys)
             n = len(keys)
             W = _padded(n)
-            keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
             _, found = self.search(keys)
-            b1, b2, fp = self._slot_candidates(keys_j)
-            spb = cfg.slots_per_bucket
-            idx0 = self.index[0].reshape(cfg.n_buckets, spb)
-            rows = jnp.concatenate([idx0[b1], idx0[b2]], axis=1)
-            offs = jnp.concatenate(
-                [b1[:, None] * spb + jnp.arange(spb)[None],
-                 b2[:, None] * spb + jnp.arange(spb)[None]], axis=1)
-            page_keys = self.log[SL.slot_ptr(rows), 2]
-            is_match = ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
-                        & (page_keys == keys_j[:, None]))
-            slot = jnp.take_along_axis(offs, jnp.argmax(is_match, 1)[:, None],
-                                       1)[:, 0]
-            v_old = jnp.take_along_axis(rows,
-                                        jnp.argmax(is_match, 1)[:, None],
-                                        1)[:, 0]
-            act = jnp.asarray(_pad(found, W, False)) & is_match.any(1)
-            self.key, k = jax.random.split(self.key)
-            res = snapshot_epoch(self.index, jnp.where(act, slot, -1), v_old,
+            # a found key's slot is its match: the select's empty-slot
+            # fallback never acts
+            slot_i, v_old, self.key, k = _upsert_select(
+                self.index, self.page_keys, self._keys_out(keys, W),
+                _pad(found, W, False), self.key,
+                slots_per_bucket=self.cfg.slots_per_bucket)
+            res = snapshot_epoch(self.index, slot_i, v_old,
                                  jnp.zeros_like(v_old), k)
             self.index = res.index
             self._count["delete.epochs"].inc()
@@ -451,7 +535,8 @@ class KVPool:
             stats = {"chunks": 0, "used_pages": 0, "reclaimed": 0, "redone": 0}
             chunks = np.where(self.grant == cid + 1)[0]
             stats["chunks"] = len(chunks)
-            log, = self._fetch("kv.pool.recover", self.log)
+            log, recs = self._fetch("kv.pool.recover", self.log,
+                                    self.page_keys)
             new_owner = reassign_to if reassign_to is not None else cid
             sl = self._slab(new_owner)
             for c in chunks:
@@ -470,8 +555,9 @@ class KVPool:
                     committed = bool(log[p, 0] & (1 << 30))
                     if not committed and log[p, 1] in (OP_INSERT, OP_UPDATE):
                         # redo: re-run the index write for this page (§5.3 c1)
-                        ok = self.insert_batch(new_owner,
-                                               np.array([log[p, 2]], np.int32),
+                        # with the page's own key: its fold and record
+                        row = np.concatenate([log[p, 2:3], recs[p]])[None]
+                        ok = self.insert_batch(new_owner, row,
                                                np.array([p], np.int32),
                                                opcode=int(log[p, 1]))
                         stats["redone"] += int(ok[0])

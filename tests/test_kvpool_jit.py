@@ -1,8 +1,10 @@
 """The write path's jitted steps (``KVPool.write_pages`` and
 ``KVPool.insert_batch``) against the eager jnp code they replaced, kept
-here as the oracle: on the same seed, every device array, the pool's
-PRNG key and the returned mask must be bit-identical after every call.
-Also: the steps compile once per padded batch shape."""
+here as the oracle (with the RACE slot-match rule written out again: fp,
+slot in use, and the page's key record equal to the key's): on the same
+seed, every device array, the pool's PRNG key and the returned mask must
+be bit-identical after every call.  Also: the steps compile once per
+padded batch shape."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +12,8 @@ import pytest
 
 from repro.serving import PoolConfig
 from repro.serving import slots_jax as SL
-from repro.serving.kvpool import KVPool, OP_INSERT, OP_UPDATE, _pad, _padded
+from repro.serving.kvpool import (KVPool, OP_INSERT, OP_UPDATE, _pad,
+                                  _padded, as_key_rows)
 from repro.serving.snapshot_jax import snapshot_epoch
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -18,6 +21,7 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # ------------------------------------------------ the eager oracle ------
 def _eager_write_pages(pool, cid, pages, keys, opcode):
+    keys = as_key_rows(keys)
     sl = pool._slab(cid)
     n = len(pages)
     nxt = np.array([sl.free[0] + 1 if sl.free else 0] * n, np.int32)
@@ -28,22 +32,27 @@ def _eager_write_pages(pool, cid, pages, keys, opcode):
         sl.last_alloc = int(pages[-1]) + 1
     W = _padded(n)
     entries = np.zeros((W, 4), np.int32)
-    entries[:n, 1:] = np.stack([np.full(n, opcode), keys, np.ones(n)], 1)
+    entries[:n, 1:] = np.stack([np.full(n, opcode), keys[:, 0], np.ones(n)],
+                               1)
     links = np.zeros((W, 2), np.int32)
     links[:n] = np.stack([nxt, prv], 1)
     pg = jnp.asarray(_pad(np.asarray(pages, np.int32), W, pool.cfg.n_pages))
     pool.log = pool.log.at[pg].set(jnp.asarray(entries), mode="drop")
     pool.chain = pool.chain.at[pg].set(jnp.asarray(links), mode="drop")
+    pool.page_keys = pool.page_keys.at[pg[:n]].set(jnp.asarray(keys[:, 1:]))
 
 
 def _eager_insert_batch(pool, keys, pages):
     cfg = pool.cfg
+    keys = as_key_rows(keys)
     n = len(keys)
     W = _padded(n)
-    keys_j = jnp.asarray(_pad(np.asarray(keys, np.int32), W, 0))
+    fold = jnp.asarray(_pad(keys[:, 0], W, 0))
+    recs = jnp.zeros((W, keys.shape[1] - 1), jnp.int32).at[:n].set(
+        keys[:, 1:])
     pages_j = jnp.asarray(_pad(np.asarray(pages, np.int32), W, 0))
-    b1, b2 = SL.bucket_pair(keys_j, cfg.n_buckets)
-    fp = SL.fingerprint(keys_j)
+    b1, b2 = SL.bucket_pair(fold, cfg.n_buckets)
+    fp = SL.fingerprint(fold)
     v_new = SL.pack_slot(fp, pages_j)
     done = np.arange(W) >= n
     spb = cfg.slots_per_bucket
@@ -53,9 +62,9 @@ def _eager_insert_batch(pool, keys, pages):
         offs = jnp.concatenate([b1[:, None] * spb + jnp.arange(spb)[None],
                                 b2[:, None] * spb + jnp.arange(spb)[None]],
                                axis=1)
-        page_keys = pool.log[SL.slot_ptr(rows), 2]
+        page_keys = pool.page_keys[SL.slot_ptr(rows)]       # (W, 2spb, 6)
         is_match = ((SL.slot_fp(rows) == fp[:, None]) & (rows != 0)
-                    & (page_keys == keys_j[:, None]))
+                    & jnp.all(page_keys == recs[:, None, :], axis=-1))
         is_empty = rows == 0
         cand = jnp.where(is_match.any(1), jnp.argmax(is_match, 1),
                          jnp.argmax(is_empty, 1))
@@ -87,13 +96,14 @@ class Twin:
         self.old = KVPool(cfg, seed=seed)
 
     def same(self):
-        for name in ("index", "log", "chain", "free_bitmap", "key"):
+        for name in ("index", "log", "page_keys", "chain", "free_bitmap",
+                     "key"):
             np.testing.assert_array_equal(np.asarray(getattr(self.new, name)),
                                           np.asarray(getattr(self.old, name)),
                                           err_msg=name)
 
     def upsert(self, keys, opcode=OP_INSERT, cid=0) -> np.ndarray:
-        keys = np.asarray(keys, np.int32)
+        keys = as_key_rows(keys)
         pages = self.new.alloc_pages(cid, len(keys))
         np.testing.assert_array_equal(self.old.alloc_pages(cid, len(keys)),
                                       pages)
@@ -177,6 +187,26 @@ def test_same_slot_contention_takes_more_than_one_attempt():
     same = _same_bucket_and_fp(SMALL.n_buckets, 5)
     t.upsert(same)
     assert t.counter("upsert.attempts") > t.counter("upsert.calls") == 1
+
+
+def test_keys_with_one_fold_hold_slots_of_their_own():
+    """``x`` and ``x + 2**31`` share a fold, so a bucket pair and a
+    fingerprint: only their records tell them apart."""
+    t = Twin(SMALL, seed=13)
+    base = np.arange(1, 9, dtype=np.int64)
+    both = np.concatenate([base, base + 2**31])
+    rows = as_key_rows(both)
+    assert (rows[:8, 0] == rows[8:, 0]).all()
+    assert (rows[:8, 1:] != rows[8:, 1:]).any(1).all()
+    assert t.upsert(both).all()
+    ptr, found = t.new.search(both)
+    assert found.all() and len(set(ptr.tolist())) == 16
+    # each pair's second key meets its partner's page first
+    assert t.counter("search.aliased") >= 1
+    assert t.upsert(both[::-3], OP_UPDATE).all()
+    ptr2, found2 = t.new.search(both)
+    assert found2.all() and len(set(ptr2.tolist())) == 16
+    assert (ptr2 != ptr).sum() == len(both[::-3])
 
 
 def test_full_bucket_pairs_answer_not_ok():

@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.leaf_probe.kernel import leaf_probe_fwd
 from repro.kernels.race_lookup.kernel import race_lookup_fwd
+from repro.core.codec import KEY_WORDS
 from repro.serving import kvpool
 from repro.serving.snapshot_jax import snapshot_epoch
 
@@ -89,18 +90,20 @@ POOL_BUCKETS, POOL_PAGES = 1 << 20, 1 << 21
 def test_write_path_steps_compile_for_v5e(shape, step, width):
     i32 = jnp.int32
     log = shape((POOL_PAGES, 4), i32)
+    page_keys = shape((POOL_PAGES, KEY_WORDS), i32)
     vec = shape((width,), i32)
+    keys = shape((width, kvpool.KEY_COLS), i32)
     if step == "write":
         lowered = kvpool._write_step.lower(
-            log, shape((POOL_PAGES, 2), i32), vec, shape((width, 4), i32),
-            shape((width, 2), i32))
+            log, shape((POOL_PAGES, 2), i32), page_keys, vec,
+            shape((width, 4), i32), shape((width, 2), i32),
+            shape((width, KEY_WORDS), i32))
     elif step == "prepare":
-        lowered = kvpool._upsert_prepare.lower(vec, vec,
-                                               n_buckets=POOL_BUCKETS)
+        lowered = kvpool._upsert_prepare.lower(keys, vec)
     elif step == "select":
         lowered = kvpool._upsert_select.lower(
-            shape((REPLICAS, POOL_BUCKETS * SPB), i32), log, vec, vec, vec,
-            vec, shape((width,), jnp.bool_), shape((2,), jnp.uint32),
+            shape((REPLICAS, POOL_BUCKETS * SPB), i32), page_keys, keys,
+            shape((width,), jnp.bool_), shape((2,), jnp.uint32),
             slots_per_bucket=SPB)
     else:
         lowered = kvpool._upsert_commit.lower(
@@ -109,3 +112,25 @@ def test_write_path_steps_compile_for_v5e(shape, step, width):
     mem = lowered.compile().memory_analysis()
     # no step copies the O(M) index: select reads its rows in place
     assert mem.temp_size_in_bytes < REPLICAS * POOL_BUCKETS * SPB * 4
+
+
+@pytest.mark.parametrize("step", ["check", "all_check"])
+@pytest.mark.parametrize("width", [256, 4096])
+def test_search_key_checks_compile_for_v5e(shape, step, width):
+    """The lookup's key checks at the 1M-record pool: neither copies the
+    index, the log or the page keys, and each returns one word a key."""
+    i32 = jnp.int32
+    log = shape((POOL_PAGES, 4), i32)
+    page_keys = shape((POOL_PAGES, KEY_WORDS), i32)
+    keys = shape((width, kvpool.KEY_COLS), i32)
+    if step == "check":
+        lowered = kvpool._search_check.lower(
+            log, page_keys, keys, shape((width,), i32),
+            shape((width,), jnp.bool_))
+    else:
+        lowered = kvpool._search_all_check.lower(
+            shape((REPLICAS, POOL_BUCKETS * SPB), i32), log, page_keys, keys,
+            slots_per_bucket=SPB)
+    mem = lowered.compile().memory_analysis()
+    assert mem.output_size_in_bytes == width * 4
+    assert mem.temp_size_in_bytes < POOL_PAGES * 4
